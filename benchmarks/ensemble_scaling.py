@@ -31,7 +31,6 @@ import argparse
 import json
 import sys
 import time
-import warnings
 
 import jax
 
@@ -118,9 +117,6 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_ensemble_scaling.json")
     args = ap.parse_args(argv)
 
-    # silence ONLY the Pallas interpret-mode notice — a numpy RuntimeWarning
-    # (overflow, 0/0) must still reach the console before landing in the JSON
-    warnings.filterwarnings("ignore", message="Pallas LBM kernels.*")
     batches = ([int(b) for b in args.batches.split(",")] if args.batches
                else [1, 2, 4] if args.quick else [1, 2, 4, 8])
     steps = args.steps or (2 if args.quick else 20)

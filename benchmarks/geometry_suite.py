@@ -32,7 +32,6 @@ import argparse
 import json
 import sys
 import time
-import warnings
 
 import jax
 
@@ -177,9 +176,6 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_geometry_suite.json")
     args = ap.parse_args(argv)
 
-    # silence ONLY the Pallas interpret-mode notice — a numpy RuntimeWarning
-    # (overflow, 0/0) must still reach the console before landing in the JSON
-    warnings.filterwarnings("ignore", message="Pallas LBM kernels.*")
     orders = (args.orders.split(",") if args.orders
               else ["zmajor", "morton_slab"] if args.quick
               else list(TILE_ORDERS))

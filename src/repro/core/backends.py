@@ -1,8 +1,12 @@
 """Step backends for :class:`repro.core.engine.SparseTiledLBM`.
 
 A backend owns the device-resident representation of f and produces one LBM
-iteration as a pure ``state -> state`` function the engine jits (and loops
-with ``fori_loop`` in ``run``):
+iteration as a pure ``(state, tables) -> state`` function the engine jits
+(and loops with ``fori_loop`` in ``run``).  ``backend.tables`` holds every
+geometry-sized table (index, neighbour, type and boundary tables), placed on
+the device once and passed to the jitted step as an ARGUMENT: closed over,
+they would be embedded in the compiled program as constants, whose size
+then grows with the geometry (gigabytes at chip-filling sizes).
 
 * ``gather``  — one jnp gather per direction from the per-direction storage
   layout (supports every ``layout_scheme``), jnp or Pallas collision
@@ -27,12 +31,12 @@ state:
 
 * gather — a leading batch axis on f: ``ensemble_step`` is ``jax.vmap``
   of the scalar step, which keeps every replica BITWISE identical to an
-  independent engine (the index tables are closed-over constants shared
+  independent engine (the index tables are unbatched arguments shared
   across the batch).
 * fused — a B-replicated packed state ``(B*T + 1, Q, n)``: the tile axis
   is replicated B times with per-replica offsets folded into the
-  neighbour table (scratch row shared at index B*T), so ONE pallas_call
-  over a B*T grid advances all replicas while the static (Q, n) pull
+  neighbour table (scratch row shared at index B*T), so one kernel grid
+  over all B*T tiles advances every replica while the static (Q, n) pull
   perms/cases stay a single copy.
 
 Tile traversal order (``LBMConfig.tile_order``): every per-tile table a
@@ -178,17 +182,19 @@ class GatherBackend:
 
     def __init__(self, cfg, lat, tiling: Tiling, tables: StreamTables,
                  interpret: bool):
-        self.cfg, self.lat, self.tiling, self.tables = cfg, lat, tiling, tables
+        self.cfg, self.lat, self.tiling, self.stream = cfg, lat, tiling, tables
         self.interpret = interpret
         types = tiling.node_types                            # (T, n) canonical
         self._solid = jnp.asarray(types == SOLID)
-        self._bc_masks = [
-            (jnp.asarray(types == tv), spec) for tv, spec in cfg.boundaries
-        ]
-        self._split = None
+        self._bc_specs = tuple(spec for _, spec in cfg.boundaries)
+        self.tables = {
+            "solid": self._solid,
+            "bc_masks": tuple(jnp.asarray(types == tv)
+                              for tv, _ in cfg.boundaries),
+        }
         if cfg.split_stream:
             sp = tables.split
-            self._split = {
+            self.tables["split"] = {
                 "intra": jnp.asarray(sp.intra_idx),
                 "case": jnp.asarray(sp.case.astype(np.int32)),
                 "is_cross": jnp.asarray(sp.is_cross),
@@ -200,7 +206,8 @@ class GatherBackend:
                 "perms": jnp.asarray(tables.perms),
             }
         else:
-            self._gather = jnp.asarray(tables.gather_idx.reshape(lat.q, -1))
+            self.tables["gather"] = jnp.asarray(
+                tables.gather_idx.reshape(lat.q, -1))
 
     # ------------------------------------------------- layout shuffles
     def to_storage(self, f_canon: jnp.ndarray) -> jnp.ndarray:
@@ -208,7 +215,7 @@ class GatherBackend:
         if self.cfg.layout_scheme == "xyz":
             return f_canon
         return jnp.stack(
-            [f_canon[q][..., self.tables.inv_perms[q]]
+            [f_canon[q][..., self.stream.inv_perms[q]]
              for q in range(self.lat.q)]
         )
 
@@ -216,20 +223,20 @@ class GatherBackend:
         if self.cfg.layout_scheme == "xyz":
             return f_store
         return jnp.stack(
-            [f_store[q][..., self.tables.perms[q]] for q in range(self.lat.q)]
+            [f_store[q][..., self.stream.perms[q]] for q in range(self.lat.q)]
         )
 
     # ------------------------------------------------------------ step
     def initial_state(self, feq_canon: jnp.ndarray) -> jnp.ndarray:
         return self.to_storage(feq_canon)
 
-    def _collide(self, f_in):
+    def _collide(self, f_in, solid):
         if self.cfg.use_kernel:
             from repro.kernels import ops as kops
 
             return kops.collide_tiles(
                 f_in,
-                self._solid,
+                solid,
                 self.lat,
                 self.cfg.collision,
                 force=self.cfg.force,
@@ -239,30 +246,33 @@ class GatherBackend:
                                   self.cfg.force)
         return f_out
 
-    def step(self, f_store: jnp.ndarray) -> jnp.ndarray:
+    def step(self, f_store: jnp.ndarray, tab: dict) -> jnp.ndarray:
+        """One iteration; ``tab`` is :attr:`tables` (passed in, not closed
+        over, so the compiled program does not embed the geometry)."""
         q = self.lat.q
         t, n = self.tiling.num_tiles, self.tiling.nodes_per_tile
         if self.cfg.kernel_mode == "rw_only":
             # paper §4.1: read + write the node's own data, no propagation
             return f_store + 0.0
-        if self._split is not None:
+        solid = tab["solid"]
+        if "split" in tab:
             # split-phase: static interior perm + compact frontier tables
-            f_in = apply_split_stream(f_store, self._solid, **self._split)
+            f_in = apply_split_stream(f_store, solid, **tab["split"])
         else:
             # streaming + bounce-back: one gather per direction
             with phase_scope("lbm.phase.stream"):
-                f_in = jnp.take(f_store.reshape(-1), self._gather,
+                f_in = jnp.take(f_store.reshape(-1), tab["gather"],
                                 axis=0).reshape(q, t, n)
         if self.cfg.kernel_mode == "propagation_only":
             return self.to_storage(f_in)
         # open boundaries (Zou-He NEBB / constant pressure)
         with phase_scope("lbm.phase.boundary"):
-            for mask, spec in self._bc_masks:
+            for mask, spec in zip(tab["bc_masks"], self._bc_specs):
                 f_in = apply_open_boundary(f_in, mask, spec, self.lat)
         with phase_scope("lbm.phase.collide"):
-            f_out = self._collide(f_in)
+            f_out = self._collide(f_in, solid)
         with phase_scope("lbm.phase.pack"):
-            f_out = jnp.where(self._solid[None], 0.0, f_out)
+            f_out = jnp.where(solid[None], 0.0, f_out)
             return self.to_storage(f_out)
 
     # ------------------------------------------------- ensemble (B states)
@@ -270,15 +280,19 @@ class GatherBackend:
         """Replicate one storage state (Q, T, n) into (B, Q, T, n)."""
         return jnp.repeat(f_single[None], batch, axis=0)
 
-    def ensemble_step(self, fb: jnp.ndarray) -> jnp.ndarray:
+    def ensemble_tables(self, batch: int) -> dict:
+        """The batched step shares the single-state tables unchanged."""
+        return self.tables
+
+    def ensemble_step(self, fb: jnp.ndarray, tab: dict) -> jnp.ndarray:
         """One step for B independent states: vmap of the scalar step.
 
         All index tables (monolithic gather or split frontier tables) are
-        closed-over constants, loaded once for the whole batch.  Each
+        unbatched arguments, loaded once for the whole batch.  Each
         replica is bitwise identical to an unbatched step (pinned in
         tests/test_sim_ensemble.py).
         """
-        return jax.vmap(self.step)(fb)
+        return jax.vmap(self.step, in_axes=(0, None))(fb, tab)
 
     def ensemble_canonical(self, fb: jnp.ndarray) -> jnp.ndarray:
         return jax.vmap(self.canonical)(fb)
@@ -309,7 +323,8 @@ class FusedBackend:
 
     def __init__(self, cfg, lat, tiling: Tiling, tables: StreamTables,
                  interpret: bool):
-        from repro.kernels.stream_collide import build_neighbor_table
+        from repro.kernels.stream_collide import (build_neighbor_table,
+                                                  kernel_node_types)
 
         if cfg.layout_scheme != "xyz":
             raise ValueError(
@@ -317,31 +332,16 @@ class FusedBackend:
                 f"layout_scheme must be 'xyz' (got {cfg.layout_scheme!r})")
         self.cfg, self.lat, self.tiling = cfg, lat, tiling
         self.interpret = interpret
-        t, n = tiling.num_tiles, tiling.nodes_per_tile
-        q = lat.q
-
-        types = np.full((t + 1, n), SOLID, np.uint8)
-        types[:t] = tiling.node_types
-        self._types_np = types                       # host copy for ensembles
-        self._types = jnp.asarray(types)
+        self._types_np = kernel_node_types(tiling.node_types)  # (T+1, 1, n)
         self._nbrs_np = build_neighbor_table(tiling, cfg.periodic)
-        self._nbrs = jnp.asarray(self._nbrs_np)
         self._solid = jnp.asarray(tiling.node_types == SOLID)
-
-        self._bc = None
         self._bc_np = (boundary_pass_tables(
-            tiling.node_types, tables.gather_idx, cfg.boundaries, q, n)
+            tiling.node_types, tables.gather_idx, cfg.boundaries, lat.q,
+            tiling.nodes_per_tile)
             if cfg.boundaries and cfg.kernel_mode == "full" else None)
-        if self._bc_np is not None:
-            bt, packed, type_masks, solid_b = self._bc_np
-            self._bc = {
-                "tiles": jnp.asarray(bt),
-                "gather": jnp.asarray(packed),
-                "type_masks": jnp.asarray(type_masks),
-                "solid": jnp.asarray(solid_b),
-                "specs": tuple(spec for _, spec in cfg.boundaries),
-            }
-        self._ens_tables: dict[int, tuple] = {}
+        self._bc_specs = tuple(spec for _, spec in cfg.boundaries)
+        self._ens_tables: dict[int, dict] = {}
+        self.tables = self.ensemble_tables(1)
 
     # ------------------------------------------------------------ state
     def initial_state(self, feq_canon: jnp.ndarray) -> jnp.ndarray:
@@ -355,25 +355,32 @@ class FusedBackend:
         return jnp.moveaxis(f_packed[:-1], 0, 1)       # (Q, T, n)
 
     # ------------------------------------------------------------ step
-    def step(self, f: jnp.ndarray) -> jnp.ndarray:
+    def step(self, f: jnp.ndarray, tab: dict) -> jnp.ndarray:
+        """One fused-kernel step over every tile row of ``f``.  ``tab`` is
+        :attr:`tables` for a single state, or ``ensemble_tables(B)`` for a
+        B-replicated one (one pallas_call grid over all B*T tiles)."""
         from repro.kernels.stream_collide import stream_collide_tiles
 
         cfg = self.cfg
         with phase_scope("lbm.phase.stream_collide"):
             out = stream_collide_tiles(
-                f, self._types, self._nbrs, self.lat, cfg.collision,
+                f, tab["types"], tab["nbrs"], self.lat, cfg.collision,
                 a=cfg.a, force=cfg.force, interpret=self.interpret,
                 mode=cfg.kernel_mode, node_order=cfg.node_order)
-        if self._bc is not None:
-            tab = self._bc
+        if "bc" in tab:
+            bc = tab["bc"]
             out = nebb_boundary_pass(
-                f, out, self.lat, cfg.collision, cfg.force, tab["specs"],
-                tab["tiles"], tab["gather"], tab["type_masks"], tab["solid"])
+                f, out, self.lat, cfg.collision, cfg.force, self._bc_specs,
+                bc["tiles"], bc["gather"], bc["type_masks"], bc["solid"])
         return out
 
+    # the step is shape-generic: B is carried by the tables and the state
+    ensemble_step = step
+
     # ------------------------------------------------- ensemble (B states)
-    def _ensemble_tables(self, batch: int):
-        """Replicated kernel tables for a B-replicated packed state.
+    def ensemble_tables(self, batch: int) -> dict:
+        """Device tables for a B-replicated packed state (B = 1 is the
+        single-state :attr:`tables`), built and placed once per B.
 
         Replica b's tiles occupy rows [b*T, (b+1)*T); the single scratch
         row moves to index B*T.  The neighbour table gets the per-replica
@@ -391,10 +398,10 @@ class FusedBackend:
              for b in range(batch)]).astype(np.int32)
         types = np.concatenate([self._types_np[:t]] * batch
                                + [self._types_np[t:]])
-        bc = None
+        tab = {"types": jnp.asarray(types), "nbrs": jnp.asarray(nbrs)}
         if self._bc_np is not None:
             bt, packed, type_masks, solid_b = self._bc_np
-            bc = {
+            tab["bc"] = {
                 "tiles": jnp.asarray(np.concatenate(
                     [bt + b * t for b in range(batch)]).astype(np.int32)),
                 "gather": jnp.asarray(np.concatenate(
@@ -402,33 +409,13 @@ class FusedBackend:
                 "type_masks": jnp.asarray(
                     np.concatenate([type_masks] * batch, axis=1)),
                 "solid": jnp.asarray(np.concatenate([solid_b] * batch)),
-                "specs": self._bc["specs"],
             }
-        self._ens_tables[batch] = (jnp.asarray(types), jnp.asarray(nbrs), bc)
-        return self._ens_tables[batch]
+        self._ens_tables[batch] = tab
+        return tab
 
     def ensemble_state(self, f_single: jnp.ndarray, batch: int) -> jnp.ndarray:
         """(T+1, Q, n) packed state -> (B*T + 1, Q, n) B-replicated."""
         return jnp.concatenate([f_single[:-1]] * batch + [f_single[-1:]])
-
-    def ensemble_step(self, f: jnp.ndarray) -> jnp.ndarray:
-        """One fused-kernel step over all B replicas in a single pallas_call
-        (grid = B*T); B is inferred from the state shape."""
-        from repro.kernels.stream_collide import stream_collide_tiles
-
-        cfg = self.cfg
-        batch = (f.shape[0] - 1) // self.tiling.num_tiles
-        types, nbrs, bc = self._ensemble_tables(batch)
-        with phase_scope("lbm.phase.stream_collide"):
-            out = stream_collide_tiles(
-                f, types, nbrs, self.lat, cfg.collision,
-                a=cfg.a, force=cfg.force, interpret=self.interpret,
-                mode=cfg.kernel_mode, node_order=cfg.node_order)
-        if bc is not None:
-            out = nebb_boundary_pass(
-                f, out, self.lat, cfg.collision, cfg.force, bc["specs"],
-                bc["tiles"], bc["gather"], bc["type_masks"], bc["solid"])
-        return out
 
     def ensemble_canonical(self, f: jnp.ndarray) -> jnp.ndarray:
         """(B*T + 1, Q, n) -> (B, Q, T, n) for diagnostics."""

@@ -22,6 +22,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from . import collision as col
 from .lattice import Lattice
 
 
@@ -62,12 +63,17 @@ def apply_open_boundary(
     unknown, outgoing, parallel = _direction_sets(lat, spec.normal)
     n = jnp.asarray(np.asarray(spec.normal, np.float64), dtype=dtype)
 
-    f_par = jnp.sum(f[parallel], axis=0)
-    f_out = jnp.sum(f[outgoing], axis=0)
+    # sums of static rows in a fixed order, not f[index_array]: XLA
+    # compiles the latter as a gather that it splits into more pieces the
+    # larger the domain, and a reduce may be reassociated differently from
+    # one program to the next (the split and ensemble steps must stay
+    # bitwise equal to the monolithic one)
+    f_par = sum(f[int(i)] for i in parallel)
+    f_out = sum(f[int(i)] for i in outgoing)
 
     if spec.kind == "velocity":
         u = jnp.asarray(np.asarray(spec.velocity, np.float64), dtype=dtype)
-        un = jnp.dot(u, n)
+        un = jnp.dot(u, n, precision=col.HIGHEST)
         rho = (f_par + 2.0 * f_out) / (1.0 - un)
         u_full = jnp.broadcast_to(
             u.reshape((3,) + (1,) * mask.ndim), (3,) + mask.shape
@@ -94,7 +100,7 @@ def apply_open_boundary(
     for i in unknown:
         i = int(i)
         opp = int(lat.opp[i])
-        eu = jnp.tensordot(e[i], u_full, axes=1)
+        eu = jnp.tensordot(e[i], u_full, axes=1, precision=col.HIGHEST)
         rebuilt = f[opp] + 2.0 * w[i] * rho_full * eu * 3.0
         new_f = new_f.at[i].set(jnp.where(mask, rebuilt, f[i]))
     return new_f

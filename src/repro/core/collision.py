@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,6 +41,11 @@ class CollisionConfig:
         return (self.tau - 0.5) / 3.0
 
 
+# Full-precision contractions: the TPU's default rounds float32 matmul
+# operands to bfloat16, which costs the velocity moments three digits.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _e_matrix(lat: Lattice, dtype) -> jnp.ndarray:
     return jnp.asarray(lat.e.astype(np.float64), dtype=dtype)  # (Q, 3)
 
@@ -51,7 +57,7 @@ def macroscopics(f: jnp.ndarray, lat: Lattice, fluid: str):
     """
     e = _e_matrix(lat, f.dtype)
     rho = jnp.sum(f, axis=0)
-    j = jnp.tensordot(e.T, f, axes=1)  # (3, ...)
+    j = jnp.tensordot(e.T, f, axes=1, precision=HIGHEST)  # (3, ...)
     if fluid == QUASI_COMPRESSIBLE:
         u = j / rho
     else:
@@ -67,7 +73,7 @@ def equilibrium(rho: jnp.ndarray, u: jnp.ndarray, lat: Lattice, fluid: str):
     dtype = u.dtype
     e = _e_matrix(lat, dtype)                      # (Q, 3)
     w = jnp.asarray(lat.w, dtype=dtype)            # (Q,)
-    eu = jnp.tensordot(e, u, axes=1)               # (Q, ...)
+    eu = jnp.tensordot(e, u, axes=1, precision=HIGHEST)   # (Q, ...)
     u2 = jnp.sum(u * u, axis=0)                    # (...)
     # cs^2 = 1/3: 1/cs^2 = 3, 1/(2 cs^4) = 4.5, 1/(2 cs^2) = 1.5
     poly = 3.0 * eu + 4.5 * eu * eu - 1.5 * u2     # (Q, ...)
@@ -102,7 +108,7 @@ def collide(
         f_out = f + (feq - f) / cfg.tau
     else:
         a = collision_matrix(lat, cfg.tau, dtype=f.dtype)
-        f_out = f + jnp.tensordot(a, feq - f, axes=1)
+        f_out = f + jnp.tensordot(a, feq - f, axes=1, precision=HIGHEST)
     return f_out, rho, u
 
 

@@ -24,9 +24,8 @@ The step itself is pluggable (``LBMConfig.backend``, see
   diagnostics, zero layout shuffles inside ``step``/``run``.
 
 The same engine runs:
-* on CPU for validation (Pallas kernels in interpret mode — the default
-  when no tpu/gpu backend is active; a warning is emitted so interpreted
-  numbers are never mistaken for benchmarks),
+* on CPU for validation (Pallas kernels in interpret mode), and compiled
+  on the TPU (``repro.kernels.ops.default_interpret``),
 * distributed via ``repro.dist.lbm.ShardedLBM`` (slab decomposition of the
   tile grid — the multi-GPU extension the paper leaves as future work),
   which composes its halo exchange with either backend per slab.
@@ -34,13 +33,13 @@ The same engine runs:
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels.ops import resolve_interpret
 
 from . import collision as col
 from .backends import BACKENDS, make_backend
@@ -83,26 +82,10 @@ class LBMConfig:
     u0: tuple[float, float, float] = (0.0, 0.0, 0.0)
     backend: str = "gather"                   # 'gather' | 'fused'
     use_kernel: bool = False                  # gather backend: Pallas collision
-    # Pallas interpret mode: None = auto (interpret unless on tpu/gpu)
+    # Pallas interpret mode: None = auto (interpret on cpu, compile on tpu)
     kernel_interpret: bool | None = None
     # paper §4.1 kernel variants: 'full' | 'propagation_only' | 'rw_only'
     kernel_mode: str = "full"
-
-
-def _resolve_interpret(cfg: LBMConfig) -> bool:
-    from repro.kernels.ops import resolve_interpret
-
-    # the fused kernel is TPU-only Pallas (scalar prefetch); the collision
-    # kernel lowers on tpu and gpu
-    interpret = resolve_interpret(cfg.kernel_interpret,
-                                  tpu_only=cfg.backend == "fused")
-    if interpret and (cfg.backend == "fused" or cfg.use_kernel):
-        warnings.warn(
-            "Pallas LBM kernels will run in INTERPRET mode (jax backend="
-            f"{jax.default_backend()!r}); results are for validation, not "
-            "benchmarking. Pass kernel_interpret=False on tpu/gpu.",
-            RuntimeWarning, stacklevel=3)
-    return interpret
 
 
 class SparseTiledLBM:
@@ -126,7 +109,7 @@ class SparseTiledLBM:
             split=cfg.split_stream,
         )
         self.dtype = jnp.dtype(cfg.dtype)
-        self.kernel_interpret = _resolve_interpret(cfg)
+        self.kernel_interpret = resolve_interpret(cfg.kernel_interpret)
 
         self.backend = make_backend(cfg.backend, cfg, self.lat, self.tiling,
                                     self.tables, self.kernel_interpret)
@@ -173,7 +156,7 @@ class SparseTiledLBM:
     # ------------------------------------------------------------------ step
     def step(self, steps: int = 1) -> None:
         for _ in range(steps):
-            self.f = self._step_fn(self.f)
+            self.f = self._step_fn(self.f, self.backend.tables)
         reg = obs.get_metrics()
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)
@@ -182,15 +165,15 @@ class SparseTiledLBM:
         """Run ``steps`` iterations inside a single jitted fori_loop."""
         if steps not in self._multi_cache:
             fn = jax.jit(
-                lambda f: jax.lax.fori_loop(
-                    0, steps, lambda i, x: self.backend.step(x), f
+                lambda f, tab: jax.lax.fori_loop(
+                    0, steps, lambda i, x: self.backend.step(x, tab), f
                 ),
                 donate_argnums=0,
             )
             self._multi_cache[steps] = fn
         tr = obs.get_tracer()
         with tr.span("lbm.run", steps=steps), obs.annotation("lbm.run"):
-            self.f = self._multi_cache[steps](self.f)
+            self.f = self._multi_cache[steps](self.f, self.backend.tables)
         reg = obs.get_metrics()
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)

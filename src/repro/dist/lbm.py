@@ -41,17 +41,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core import collision as col
-from repro.core.engine import LBMConfig, _resolve_interpret
+from repro.core.engine import LBMConfig
 from repro.core.boundary import apply_open_boundary
 from repro.core.lattice import get_lattice
 from repro.core.streaming import build_stream_tables
 from repro.core.tiling import (SLAB_COMPATIBLE_ORDERS, SOLID, Tiling,
                                tile_geometry)
+from repro.kernels.ops import resolve_interpret
 
 
 # ==========================================================================
@@ -234,7 +234,7 @@ class ShardedLBM:
             raise ValueError("backend='fused' requires layout_scheme='xyz'")
         if cfg.split_stream and self.fused:
             raise ValueError("split_stream requires backend='gather'")
-        self.kernel_interpret = _resolve_interpret(cfg)
+        self.kernel_interpret = resolve_interpret(cfg.kernel_interpret)
 
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         n_slab = math.prod(sizes[a] for a in axis)
@@ -430,8 +430,10 @@ class ShardedLBM:
         q, tp, n = self.lat.q, plan.t_pad, plan.nodes_per_tile
         d_cnt, dummy = plan.n_dev, plan.t_pad - 1
 
-        tbl["types"] = types
-        specs["types"] = P("slab", None, None)
+        # the kernel's (Tp, 1, n) int32 type table per slab; padding tiles
+        # and the dummy slot are SOLID (0) already
+        tbl["types"] = types.astype(np.int32)[:, :, None, :]
+        specs["types"] = P("slab", None, None, None)
         nbrs = np.full((d_cnt, dummy, 27), dummy, np.int32)
         for d, lt in enumerate(plan.local_tilings):
             nb = build_neighbor_table(lt, periodic)     # scratch = t_loc
@@ -608,10 +610,10 @@ class ShardedLBM:
         step_specs = {k: v for k, v in self._tbl_specs.items()}
 
         def raw_step(f, tbl):
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(self._f_spec, step_specs),
-                out_specs=self._f_spec, check_rep=False)(f, tbl)
+                out_specs=self._f_spec, check_vma=False)(f, tbl)
 
         self._raw_step = raw_step
         self._step_fn = jax.jit(raw_step, donate_argnums=0)

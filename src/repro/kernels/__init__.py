@@ -8,9 +8,8 @@
   state in this kernel's packed (T+1, Q, n) layout persistently.
 * ``flash.py`` — attention kernel for the LM stack (unrelated to LBM).
 
-Kernels run compiled on real accelerators (collision: tpu/gpu; fused:
-tpu only — its scalar prefetch is TPU-specific) and in interpret mode
-elsewhere; see ``ops.default_interpret``.
+Kernels run compiled on the TPU and in interpret mode on the CPU; any
+other platform is refused (``ops.default_interpret``).
 """
 from .ops import collide_tiles, default_interpret, resolve_interpret
 from .stream_collide import (build_neighbor_table, pack_engine_state,

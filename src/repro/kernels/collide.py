@@ -44,9 +44,14 @@ def _signed_sum(terms):
     return acc
 
 
-def _collide_block(f, solid, a_mat, lat: Lattice, cfg: col.CollisionConfig, force):
-    """Collision math on one (Q, R, L) block, e/w unrolled as scalars."""
-    dtype = f.dtype
+def _equilibrium_rows(f, solid, lat: Lattice, cfg: col.CollisionConfig,
+                      force):
+    """Macroscopics + equilibrium over Q same-shape 2-D arrays ``f[i]``,
+    e/w unrolled as scalars.  Returns the Q equilibrium arrays as a list.
+
+    Rows stay 2-D so the math lowers on Mosaic both for the (Q, R, L)
+    collision kernel (``f[i]`` is an (R, L) slab) and for the fused kernel
+    (``f[i]`` is one (1, n) direction row)."""
     q = lat.q
     ex, ey, ez = lat.ex, lat.ey, lat.ez
     w = lat.w
@@ -97,7 +102,14 @@ def _collide_block(f, solid, a_mat, lat: Lattice, cfg: col.CollisionConfig, forc
             feqs.append(wi * rho * (1.0 + poly))
         else:
             feqs.append(wi * (rho + poly))
-    feq = jnp.stack(feqs)
+    return feqs
+
+
+def _collide_block(f, solid, a_mat, lat: Lattice, cfg: col.CollisionConfig, force):
+    """Collision math on one (Q, R, L) block, e/w unrolled as scalars."""
+    dtype = f.dtype
+    q = lat.q
+    feq = jnp.stack(_equilibrium_rows(f, solid, lat, cfg, force))
 
     if cfg.model == col.LBGK:
         f_out = f + (feq - f) * (1.0 / cfg.tau)

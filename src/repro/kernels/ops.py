@@ -7,12 +7,11 @@ wrapper: the fused engine backend keeps its state in the kernel's packed
 (T+1, Q, n) layout persistently (see ``repro.core.backends``), so nothing
 needs packing per step.
 
-Interpret mode: Pallas kernels run compiled on tpu/gpu and interpreted
-elsewhere (this CPU container).  ``interpret=None`` everywhere means
-"auto": :func:`default_interpret` picks based on ``jax.default_backend()``,
-so a real TPU run never silently falls into the interpreter — and when the
-interpreter IS used for a kernel path, the engine warns once at
-construction (see ``repro.core.engine``).
+Interpret mode: Pallas kernels run compiled on the TPU and interpreted on
+the CPU (the test suite).  ``interpret=None`` everywhere means "auto":
+:func:`default_interpret` picks from ``jax.default_backend()`` and refuses
+any other platform, so no run falls into the interpreter where a device
+was expected.
 """
 from __future__ import annotations
 
@@ -27,21 +26,24 @@ from repro.core.lattice import Lattice
 from .collide import LANES, collide_pallas
 
 
-def default_interpret(tpu_only: bool = False) -> bool:
-    """Interpret Pallas kernels unless a real accelerator backend is active.
+def default_interpret() -> bool:
+    """True on the CPU (interpret), False on the TPU (compile).
 
-    ``tpu_only``: the kernel uses TPU-specific Pallas features (scalar
-    prefetch — the fused stream+collide kernel), so only a TPU backend can
-    run it compiled; on gpu it must fall back to the interpreter rather
-    than fail to lower.
+    Any other platform raises: the kernels are TPU Pallas (the fused one
+    scalar-prefetches its neighbour table), and interpreting them there
+    would hide the missing device behind a slow, valid-looking run.
     """
-    compiled_on = ("tpu",) if tpu_only else ("tpu", "gpu")
-    return jax.default_backend() not in compiled_on
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas LBM kernels compile on tpu and are interpreted on cpu; "
+            f"the jax backend is {backend!r}")
+    return backend == "cpu"
 
 
-def resolve_interpret(flag: bool | None, tpu_only: bool = False) -> bool:
+def resolve_interpret(flag: bool | None) -> bool:
     """Resolve an ``interpret`` tri-state (None = auto) to a bool."""
-    return default_interpret(tpu_only) if flag is None else bool(flag)
+    return default_interpret() if flag is None else bool(flag)
 
 
 def _pack(f: jnp.ndarray, solid: jnp.ndarray, block_rows: int):

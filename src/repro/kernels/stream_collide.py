@@ -3,7 +3,8 @@
 One kernel instance = one tile (grid over non-empty tiles).  The paper's
 shared-memory copy of the local tileMap (Fig. 11) becomes SCALAR-PREFETCHED
 neighbour indices: the per-offset BlockSpec index_maps read the neighbour
-tile id from the prefetched (T, 27) table, so every pull source streams
+tile id from the prefetched (T, 27) table (one chunk of ``TILES_PER_CALL``
+tiles per call), so every pull source streams
 HBM→VMEM as a whole data block — the TPU analogue of the paper's "minimal
 fully-utilised transactions" (DESIGN.md §2).
 
@@ -32,11 +33,10 @@ bandwidth ceiling probe).
 Collision reuses the tile-pair collide math (kernels/collide.py) — LBGK is
 pure VPU; LBMRT contracts the 19x19 collision matrix on the MXU.
 Validated in interpret mode against SparseTiledLBM in
-tests/test_kernels_fused.py; identical code compiles for TPU.
+tests/test_kernels_fused.py; the same code compiles for TPU v5e
+(tests/test_tpu_compile.py).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -49,9 +49,13 @@ from repro.core.lattice import Lattice
 from repro.core.tiling import (NEIGHBOR_OFFSETS, SOLID, Tiling,
                                neighbor_offset_index)
 
-from .collide import _collide_block
+from .collide import _equilibrium_rows
 
 MODES = ("full", "propagation_only", "rw_only")
+
+# tiles per pallas_call: the call's (tiles, 27) int32 neighbour slice
+# (442 KiB) must fit the scalar memory with room to spare
+TILES_PER_CALL = 4096
 
 _PULL_CACHE: dict[tuple, tuple] = {}
 
@@ -149,44 +153,73 @@ def packed_gather_indices(gather_idx: np.ndarray, q: int, t: int,
 
 def make_kernel(lat: Lattice, cfg: col.CollisionConfig, n_offsets: int,
                 force=None, mode: str = "full"):
-    opp = lat.opp
+    """Kernel body for one tile.
+
+    Mosaic constraints shape it: every in-kernel value is 2-D ((Q, n)
+    blocks or (1, n) direction rows), and the pull is a 2-D lane gather
+    ``take_along_axis(block, perms, axis=1)`` over the whole (Q, n) block
+    (a 1-D ``jnp.take`` per direction does not lower; a one-hot (n, n)
+    selection on the MXU would cost Q*n^2 MACs per block and round float32
+    operands unless run at HIGHEST precision, while the gather is exact
+    and stays on the VPU/XLU).  The static row
+    permutation ``f[opp]`` and the per-direction rows the collision needs
+    go through the output block itself as a staging buffer: single-row
+    ref loads/stores lower where value-level row shuffles do not, and the
+    block is fully overwritten before the kernel returns.
+    """
+    q = lat.q
+    opp = [int(o) for o in lat.opp]
     mrt = cfg.model == col.LBMRT and mode == "full"
 
-    def kernel(nb_ref, own_f, own_t, perms_ref, cases_ref, *rest):
+    def kernel(start_ref, nb_ref, own_f, own_t, perms_ref, cases_ref, *rest):
+        # rest: (f, types) x n_offsets, [A], aliased output buffer, out
         out_ref = rest[-1]
-        if mrt:
-            a_ref = rest[-2]
-            nbr = rest[:-2]
-        else:
-            a_ref = None
-            nbr = rest[:-1]                   # (f_off, t_off) x n_offsets
-        f_own = own_f[0]                      # (Q, n) — storage dtype
-        t_own = own_t[0]                      # (n,)
+        a_ref = rest[-3] if mrt else None
+        nbr = rest[:2 * n_offsets]            # (f_off, t_off) x n_offsets
+        perms = perms_ref[...]                # (Q, n) int32
+        cases = cases_ref[...]                # (Q, n) int32
+        n = perms.shape[1]
 
-        pulled = [f_own[0]]
-        for q in range(1, lat.q):
-            perm = perms_ref[q]
-            case = cases_ref[q]
-            src_f = jnp.take(f_own[q], perm)
-            src_t = jnp.take(t_own, perm)
-            for c in range(n_offsets):
-                f_nb = nbr[2 * c][0]
-                t_nb = nbr[2 * c + 1][0]
-                hit = case == (c + 1)
-                src_f = jnp.where(hit, jnp.take(f_nb[q], perm), src_f)
-                src_t = jnp.where(hit, jnp.take(t_nb, perm), src_t)
-            bounce = src_t == SOLID
-            pulled.append(jnp.where(bounce, f_own[int(opp[q])], src_f))
-        f_in = jnp.stack(pulled)              # (Q, n)
+        def pull(f_blk, t_row):
+            """(Q, n) sources and their node types from one tile block."""
+            t_q = jnp.broadcast_to(t_row, (q, n))
+            return (jnp.take_along_axis(f_blk, perms, axis=1),
+                    jnp.take_along_axis(t_q, perms, axis=1))
 
+        t_own = own_t[0]                      # (1, n) int32
+        src_f, src_t = pull(own_f[0], t_own)
+        for c in range(n_offsets):
+            hit = cases == (c + 1)
+            nf, nt = pull(nbr[2 * c][0], nbr[2 * c + 1][0])
+            src_f = jnp.where(hit, nf, src_f)
+            src_t = jnp.where(hit, nt, src_t)
+
+        # half-way bounce-back: a solid source returns the node's own
+        # opposite population, f_own[opp[q]]
+        for i in range(q):
+            out_ref[0, i:i + 1, :] = own_f[0, opp[i]:opp[i] + 1, :]
+        f_in = jnp.where(src_t == SOLID, out_ref[0], src_f)   # (Q, n)
+        out_ref[0] = f_in
         if mode == "propagation_only":
-            out_ref[0] = f_in.astype(out_ref.dtype)
             return
-        solid_here = t_own == SOLID
-        a_mat = a_ref[...] if mrt else None
-        f_out = _collide_block(f_in[:, None, :], solid_here[None, :],
-                               a_mat, lat, cfg, force)[:, 0, :]
-        out_ref[0] = f_out.astype(out_ref.dtype)
+
+        solid_row = t_own == SOLID            # (1, n)
+        rows = [out_ref[0, i:i + 1, :] for i in range(q)]
+        feq = _equilibrium_rows(rows, solid_row, lat, cfg, force)
+        if not mrt:
+            for i in range(q):
+                f_out = rows[i] + (feq[i] - rows[i]) * (1.0 / cfg.tau)
+                out_ref[0, i:i + 1, :] = jnp.where(solid_row, 0.0, f_out)
+            return
+        # MRT: (Q, Q) x (Q, n) on the MXU; the delta rows are staged
+        # through the output block to form the (Q, n) operand
+        for i in range(q):
+            out_ref[0, i:i + 1, :] = feq[i] - rows[i]
+        f_out = f_in + jnp.dot(a_ref[...], out_ref[0],
+                               preferred_element_type=f_in.dtype,
+                               precision=jax.lax.Precision.HIGHEST)
+        out_ref[0] = jnp.where(jnp.broadcast_to(t_own, (q, n)) == SOLID,
+                               0.0, f_out)
 
     return kernel
 
@@ -210,20 +243,20 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
     """One fused LBM step over all tiles.
 
     f:          (T+1, Q, n) — scratch tile at index T must be zero
-    node_types: (T+1, n) uint8 — scratch tile must be SOLID
+    node_types: (T+1, 1, n) int32 (:func:`kernel_node_types`) — scratch
+                tile must be SOLID
     neighbors:  (T, 27) int32 — empty/out-of-grid entries = T (scratch)
     mode:       'full' | 'propagation_only' | 'rw_only' (paper §4.1)
     node_order: within-tile node enumeration the caller's f/node_types use
                 (repro.core.tiling.NODE_ORDERS); the static pull tables are
                 remapped to match
-    interpret:  None = auto (interpret unless on tpu — this kernel's scalar
-                prefetch is TPU-specific Pallas and does not lower on gpu)
+    interpret:  None = auto (:func:`repro.kernels.ops.default_interpret`)
     Returns the post-step (T+1, Q, n) (scratch row zeroed).
     """
     from .ops import resolve_interpret
 
     assert mode in MODES, mode
-    interpret = resolve_interpret(interpret, tpu_only=True)
+    interpret = resolve_interpret(interpret)
     t1, q, n = f.shape
     t = t1 - 1
 
@@ -241,46 +274,79 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
     offsets, perms_np, cases_np = _pull_geometry(lat, a, node_order)
     kernel = make_kernel(lat, cfg, len(offsets), force, mode)
 
+    assert node_types.shape == (t1, 1, n), node_types.shape
+    nw = neighbors.shape[1]
     perms = jnp.asarray(perms_np)
-    cases = jnp.asarray(cases_np)
-    table_spec = pl.BlockSpec((q, n), lambda i, nb: (0, 0))
+    cases = jnp.asarray(cases_np, jnp.int32)
+
+    def own_map(i, start, nb):
+        return (start[0] + i, 0, 0)
+
+    table_spec = pl.BlockSpec((q, n), lambda i, start, nb: (0, 0))
     in_specs = [
-        pl.BlockSpec((1, q, n), lambda i, nb: (i, 0, 0)),   # own f
-        pl.BlockSpec((1, n), lambda i, nb: (i, 0)),          # own types
+        pl.BlockSpec((1, q, n), own_map),                    # own f
+        pl.BlockSpec((1, 1, n), own_map),                    # own types
         table_spec, table_spec,                              # perms, cases
     ]
     operands = [f, node_types, perms, cases]
     for off in offsets:
         k = neighbor_offset_index(*off)
 
-        def f_map(i, nb, _k=k):
-            return (nb[i, _k], 0, 0)
+        def nb_map(i, start, nb, _k=k):
+            return (nb[i * nw + _k], 0, 0)
 
-        def t_map(i, nb, _k=k):
-            return (nb[i, _k], 0)
-
-        in_specs.append(pl.BlockSpec((1, q, n), f_map))
-        in_specs.append(pl.BlockSpec((1, n), t_map))
+        in_specs.append(pl.BlockSpec((1, q, n), nb_map))
+        in_specs.append(pl.BlockSpec((1, 1, n), nb_map))
         operands.extend([f, node_types])
 
     if cfg.model == col.LBMRT and mode == "full":
-        in_specs.append(pl.BlockSpec((q, q), lambda i, nb: (0, 0)))
+        in_specs.append(pl.BlockSpec((q, q), lambda i, start, nb: (0, 0)))
         operands.append(jnp.asarray(col.collision_matrix_np(lat, cfg.tau),
                                     f.dtype))
+    # the output buffer rides in aliased and untouched (pl.ANY: no DMA);
+    # each call writes only its own chunk's tile blocks
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(t,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, q, n), lambda i, nb: (i, 0, 0)),
-    )
-    out = pl.pallas_call(
+    # The neighbour table is scalar-prefetched into SMEM (1 MiB on v5e),
+    # which a whole-domain table outgrows (236k tiles x 27 int32 = 25 MB).
+    # So the grid runs in chunks of ``TILES_PER_CALL`` tiles, one
+    # pallas_call per chunk inside a fori_loop.  The last chunk is clamped
+    # to end at tile T-1; the tiles it repeats are recomputed from the
+    # same read-only input, so they are rewritten with identical values.
+    chunk = min(t, TILES_PER_CALL)
+    n_chunks = -(-t // chunk)
+    call = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(chunk,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, q, n), own_map),
+        ),
         out_shape=jax.ShapeDtypeStruct((t1, q, n), f.dtype),
+        input_output_aliases={2 + len(operands): 0},
         interpret=interpret,
-    )(neighbors, *operands)
+    )
+    nb_flat = neighbors.reshape(-1)
+
+    def run_chunk(c, out):
+        start = jnp.minimum(c * chunk, t - chunk)
+        nb = jax.lax.dynamic_slice_in_dim(nb_flat, start * nw, chunk * nw)
+        return call(start[None], nb, *operands, out)
+
+    out = jax.lax.fori_loop(0, n_chunks, run_chunk, jnp.zeros_like(f))
     return zero_scratch_row(out, t)
+
+
+def kernel_node_types(node_types: np.ndarray) -> np.ndarray:
+    """(T, n) node types -> the kernel's (T+1, 1, n) int32 table with the
+    all-SOLID scratch row appended.  The unit middle axis makes every
+    per-tile block's last two dims equal the array's (Mosaic's block-shape
+    rule), and int32 is the element type Mosaic gathers over lanes."""
+    t, n = node_types.shape
+    out = np.full((t + 1, 1, n), SOLID, np.int32)
+    out[:t, 0] = node_types
+    return out
 
 
 def pack_engine_state(tiling: Tiling, f_canon, lat: Lattice):
@@ -288,8 +354,7 @@ def pack_engine_state(tiling: Tiling, f_canon, lat: Lattice):
     t, n = tiling.num_tiles, tiling.nodes_per_tile
     f = jnp.zeros((t + 1, lat.q, n), f_canon.dtype)
     f = f.at[:t].set(jnp.moveaxis(f_canon, 0, 1))
-    types = jnp.full((t + 1, n), SOLID, jnp.uint8)
-    types = types.at[:t].set(jnp.asarray(tiling.node_types))
+    types = jnp.asarray(kernel_node_types(tiling.node_types))
     nbrs = jnp.asarray(
         np.where(tiling.tile_neighbors < 0, t, tiling.tile_neighbors)
         .astype(np.int32))

@@ -1,8 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST precede every other import (jax locks device count on first init).
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jaxcache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
 """Multi-pod dry-run driver.
 
 For every (architecture x input-shape x mesh) cell:
@@ -37,6 +35,7 @@ from repro.dist.sharding import (
     batch_pspecs, cache_pspecs, make_rules_for, param_pspecs, set_axis_sizes,
     use_rules,
 )
+from repro.launch.cache import init_compile_cache
 from repro.launch.mesh import make_production_mesh, mesh_chip_count
 from repro.models.model import CausalLM
 from repro.optim.adamw import AdamWConfig, init_state
@@ -192,6 +191,7 @@ def main(argv=None):
                          "JSONL gauges (dryrun.* names, labelled by "
                          "arch/shape/mesh)")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     todo = []
     if args.all:

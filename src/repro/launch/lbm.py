@@ -31,6 +31,7 @@ from repro.core.engine import LBMConfig, SparseTiledLBM
 from repro.core.tiling import INLET, NODE_ORDERS, OUTLET, TILE_ORDERS
 from repro.data import geometry as geo
 from repro.dist.lbm import ShardedLBM
+from repro.launch.cache import init_compile_cache
 from repro.launch.mesh import make_production_mesh, mesh_chip_count
 from repro.roofline.analysis import HBM_BW, ICI_BW, PEAK_FLOPS
 from repro.roofline.hlo_cost import analyze_hlo
@@ -272,6 +273,7 @@ def main(argv=None):
                     help="write a Chrome-trace JSON (perfetto-loadable) "
                          "here; also enables jax named-scope phase names")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     if args.metrics_out or args.trace:
         # enable BEFORE any engine is built so named scopes reach the

@@ -26,6 +26,7 @@ import time
 from repro import obs
 from repro.core import collision as C
 from repro.core.engine import LBMConfig
+from repro.launch.cache import init_compile_cache
 from repro.launch.lbm import CASES, make_case, write_obs_outputs
 from repro.sim.service import SimService
 
@@ -143,6 +144,7 @@ def main(argv=None):
                     help="write a Chrome-trace JSON (perfetto-loadable) "
                          "of the nested serving spans here")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     if args.metrics_out or args.trace:
         # enable BEFORE the service is built so admission/step spans and

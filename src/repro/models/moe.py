@@ -161,7 +161,6 @@ def _pack_by(dest, values, n_bins, cap, fill=0.0):
 
 def moe_ffn_ep(p, x, cfg: MoEConfig, kind: str, mesh, dp_axes, tp_axis="model"):
     """Expert-parallel MoE under shard_map.  x: (B, S, D) -> (out, aux)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e, k = cfg.n_experts, cfg.top_k
@@ -245,12 +244,12 @@ def moe_ffn_ep(p, x, cfg: MoEConfig, kind: str, mesh, dp_axes, tp_axis="model"):
     dp = dp_axes if isinstance(dp_axes, tuple) else (dp_axes,)
     x_spec = P(dp, tp_axis, None)
     gate = p.get("gate", p["up"])      # dummy when non-gated (unused)
-    routed, aux = shard_map(
+    routed, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), P(tp_axis, None, None), P(tp_axis, None, None),
                   P(tp_axis, None, None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], gate, p["up"], p["down"])
 
     if "shared_up" in p:

@@ -4,7 +4,7 @@ The paper's central cost on sparse geometries is indirection-table
 bandwidth during propagation (and the follow-up, arXiv:1703.08015, shows
 the tables *dominate* as sparsity grows).  Batching B states over ONE
 tiling / ONE set of (split-)stream tables amortises that traffic: on the
-gather backend every index table is a closed-over constant under vmap, so
+gather backend every index table is an unbatched argument under vmap, so
 index-bytes **per node update** fall exactly as 1/B; on the fused backend
 the (T, 27) neighbour table is replicated per replica and only the static
 (Q, n) pull tables amortise (``index_bytes_per_step`` accounts per
@@ -58,6 +58,7 @@ class EnsembleLBM:
         self.backend = engine.backend
         self._feq_single = None          # lazily built template state
         self.f = self.backend.ensemble_state(self._template(), batch)
+        self._tables = self.backend.ensemble_tables(batch)
         self._step_fn = jax.jit(self.backend.ensemble_step, donate_argnums=0)
         self._multi_cache: dict[int, callable] = {}
 
@@ -86,7 +87,7 @@ class EnsembleLBM:
         tr = obs.get_tracer()
         with tr.span("lbm.ensemble.step", batch=self.batch, steps=steps):
             for _ in range(steps):
-                self.f = self._step_fn(self.f)
+                self.f = self._step_fn(self.f, self._tables)
         reg = obs.get_metrics()
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)
@@ -96,16 +97,16 @@ class EnsembleLBM:
         fori_loop (single dispatch for the whole measurement window)."""
         if steps not in self._multi_cache:
             fn = jax.jit(
-                lambda f: jax.lax.fori_loop(
-                    0, steps, lambda i, x: self.backend.ensemble_step(x), f
-                ),
+                lambda f, tab: jax.lax.fori_loop(
+                    0, steps, lambda i, x: self.backend.ensemble_step(x, tab),
+                    f),
                 donate_argnums=0,
             )
             self._multi_cache[steps] = fn
         tr = obs.get_tracer()
         with tr.span("lbm.ensemble.run", batch=self.batch, steps=steps), \
                 obs.annotation("lbm.ensemble.run"):
-            self.f = self._multi_cache[steps](self.f)
+            self.f = self._multi_cache[steps](self.f, self._tables)
         reg = obs.get_metrics()
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)
@@ -181,10 +182,10 @@ class EnsembleLBM:
         """Indirection-table bytes ONE batched step actually loads.
 
         gather: every table (monolithic gather or split frontier tables)
-        is a closed-over constant under vmap — one copy serves all B
+        is an unbatched argument under vmap — one copy serves all B
         replicas, so the figure equals the single-engine one.  fused: the
         (T, 27) neighbour table is materialised PER REPLICA
-        (``FusedBackend._ensemble_tables``), so that term scales with B;
+        (``FusedBackend.ensemble_tables``), so that term scales with B;
         only the static (Q, n) pull perms/cases stay a single copy.
         """
         if self.cfg.backend == "fused":

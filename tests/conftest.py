@@ -22,3 +22,13 @@ import pytest  # noqa: E402
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def x64():
+    """True float64 for the test (engines request float64 explicitly;
+    without the flag JAX silently truncates to float32).  Modules whose
+    every test needs it opt in with ``pytestmark =
+    pytest.mark.usefixtures("x64")``."""
+    with jax.enable_x64(True):
+        yield

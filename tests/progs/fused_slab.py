@@ -5,7 +5,6 @@ the single-device fused engine — for BOTH slab-compatible tile orderings
 contiguous).  Chained with progs/sharded_lbm.py (gather sharded ==
 single-device reference), this pins the fused slab step to the reference
 physics under reordering."""
-import warnings
 
 import jax
 
@@ -19,7 +18,6 @@ from repro.core.tiling import INLET, OUTLET, SOLID
 from repro.data.geometry import duct
 from repro.dist.lbm import ShardedLBM
 
-warnings.simplefilter("ignore", RuntimeWarning)   # interpret-mode notice
 
 g = duct(12, 12, 32, open_ends=True)
 mesh = jax.make_mesh((8,), ("data",))

@@ -18,7 +18,19 @@ cfg = LBMConfig(
 ref = SparseTiledLBM(g, cfg); ref.step(15)
 rho_r, _ = ref.fields_dense()
 mesh = jax.make_mesh((8,), ("data",))
-sh = ShardedLBM(g, cfg, mesh); sh.step(15)
+
+
+def step_synced(sh, steps):
+    """One step at a time, each finished before the next is dispatched:
+    with several 8-device programs in flight, the CPU backend's in-process
+    collectives can deadlock (a rendezvous that never fills) when the host
+    is busy."""
+    for _ in range(steps):
+        sh.step()
+        jax.block_until_ready(sh.f)
+
+
+sh = ShardedLBM(g, cfg, mesh); step_synced(sh, 15)
 rho_s, _, types, own = sh.macroscopics_own()
 a = cfg.a
 dense_s = np.full(ref.tiling.shape, np.nan)
@@ -46,7 +58,7 @@ assert abs(ref.total_mass() - sh.total_mass()) / ref.total_mass() < 1e-10
 # gather step is policy-neutral), same 1e-12 parity on owned tiles
 import dataclasses
 cfg2 = dataclasses.replace(cfg, split_stream=True, node_order="frontier_last")
-sh2 = ShardedLBM(g, cfg2, mesh); sh2.step(15)
+sh2 = ShardedLBM(g, cfg2, mesh); step_synced(sh2, 15)
 rho_s2, _, _, own2 = sh2.macroscopics_own()
 dense_s2 = np.full(ref.tiling.shape, np.nan)
 for d, lt in enumerate(sh2.plan.local_tilings):

@@ -6,11 +6,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import SHAPES, get_smoke, input_specs
 from repro.dist.sharding import (batch_pspecs, cache_pspecs, make_rules_for,
                                  param_pspecs, set_axis_sizes, use_rules)
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import CausalLM
 from repro.optim.adamw import AdamWConfig, init_state
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(model_axis=2)            # (4, 2) over 8 devices
 set_axis_sizes(mesh)
 named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                is_leaf=lambda x: isinstance(x, P))

@@ -13,11 +13,7 @@ from repro.core.tiling import INLET, OUTLET
 from repro.data.geometry import duct_wrap, random_spheres
 
 
-@pytest.fixture(autouse=True)
-def _x64():
-    from jax.experimental import enable_x64
-    with enable_x64(True):
-        yield
+pytestmark = pytest.mark.usefixtures("x64")
 
 
 TOL = 1e-12
@@ -69,7 +65,7 @@ def test_fused_matches_gather_duct_wrap_open_boundaries():
     e_g, e_f = _pair(
         g, steps=8, collision=C.CollisionConfig(tau=0.8), boundaries=BCS)
     _assert_parity(e_g, e_f)
-    assert e_f.backend._bc is not None       # boundary pass actually active
+    assert "bc" in e_f.backend.tables        # boundary pass actually active
 
 
 def test_fused_matches_gather_cavity_lid():
@@ -147,9 +143,9 @@ def _collect_primitives(jaxpr, names, skip=("pallas_call",)):
 
 def _hot_loop_primitives(eng, steps=2):
     closed = jax.make_jaxpr(
-        lambda f: jax.lax.fori_loop(0, steps,
-                                    lambda i, x: eng.backend.step(x), f)
-    )(eng.f)
+        lambda f, tab: jax.lax.fori_loop(
+            0, steps, lambda i, x: eng.backend.step(x, tab), f)
+    )(eng.f, eng.backend.tables)
     return _collect_primitives(closed.jaxpr, [])
 
 
@@ -186,13 +182,13 @@ def test_fused_boundary_pass_only_adds_tile_local_work():
     eng = SparseTiledLBM(
         g, LBMConfig(backend="fused", dtype="float64", boundaries=BCS,
                      collision=C.CollisionConfig(tau=0.8)))
-    b = int(eng.backend._bc["tiles"].shape[0])
+    b = int(eng.backend.tables["bc"]["tiles"].shape[0])
     t = eng.tiling.num_tiles
     assert b < t                             # pass is genuinely a subset
     closed = jax.make_jaxpr(
-        lambda f: jax.lax.fori_loop(0, 2,
-                                    lambda i, x: eng.backend.step(x), f)
-    )(eng.f)
+        lambda f, tab: jax.lax.fori_loop(
+            0, 2, lambda i, x: eng.backend.step(x, tab), f)
+    )(eng.f, eng.backend.tables)
 
     def _check(jaxpr):
         for eqn in jaxpr.eqns:

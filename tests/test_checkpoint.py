@@ -105,37 +105,35 @@ def test_restore_trees_from_manifest_alone(tmp_path):
     assert out["geometries"]["abc"].dtype == np.uint8
 
 
+@pytest.mark.usefixtures("x64")
 def test_torn_recovery_through_session_restore(tmp_path):
     """The new session restore path (repro.sim.service) recovers from a
     torn save: a checkpoint directory missing COMMITTED is skipped and the
     previous good step is restored bit-exactly."""
-    from jax.experimental import enable_x64
-
     from repro.core.engine import LBMConfig
     from repro.sim.service import SimService
 
-    with enable_x64(True):
-        cfg = LBMConfig(layout_scheme="paper", dtype="float64",
-                        periodic=(True, True, True), backend="gather")
-        g = np.ones((8, 8, 8), np.uint8)
-        root = str(tmp_path / "sessions")
-        svc = SimService(slots=1, checkpoint_root=root)
-        svc.submit(g, cfg, steps=5)
-        svc.step(3)
-        svc.checkpoint()
-        good = np.asarray(svc.live_sessions()[0][1])
-        svc.step(1)
-        torn = svc.checkpoint()
-        os.remove(os.path.join(torn, COMMITTED))
+    cfg = LBMConfig(layout_scheme="paper", dtype="float64",
+                    periodic=(True, True, True), backend="gather")
+    g = np.ones((8, 8, 8), np.uint8)
+    root = str(tmp_path / "sessions")
+    svc = SimService(slots=1, checkpoint_root=root)
+    svc.submit(g, cfg, steps=5)
+    svc.step(3)
+    svc.checkpoint()
+    good = np.asarray(svc.live_sessions()[0][1])
+    svc.step(1)
+    torn = svc.checkpoint()
+    os.remove(os.path.join(torn, COMMITTED))
 
-        svc2 = SimService.restore(root, slots=1)
-        sess, f = svc2.live_sessions()[0]
-        assert sess.steps_done == 3                 # the good step, not 4
-        np.testing.assert_array_equal(f, good)
-        assert f.dtype == np.float64
-        finished = svc2.run()
-        assert finished[0].result["steps"] == 5
-        assert finished[0].result["mass_drift"] < 1e-12
+    svc2 = SimService.restore(root, slots=1)
+    sess, f = svc2.live_sessions()[0]
+    assert sess.steps_done == 3                 # the good step, not 4
+    np.testing.assert_array_equal(f, good)
+    assert f.dtype == np.float64
+    finished = svc2.run()
+    assert finished[0].result["steps"] == 5
+    assert finished[0].result["mass_drift"] < 1e-12
 
 
 def test_restart_reproduces_data_stream(tmp_path):

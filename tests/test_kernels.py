@@ -99,3 +99,45 @@ def test_engine_with_kernel_matches_engine_without():
     e2.step(5)
     np.testing.assert_allclose(np.asarray(e1.f), np.asarray(e2.f),
                                rtol=3e-5, atol=3e-6)
+
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None), ("rocm", None)])
+def test_default_interpret_policy(monkeypatch, platform, interpret):
+    """Interpret on the CPU, compile on the TPU, refuse anything else
+    (no silent interpreter where a device was expected)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=platform):
+            kops.default_interpret()
+        with pytest.raises(RuntimeError, match=platform):
+            kops.resolve_interpret(None)
+    else:
+        assert kops.default_interpret() is interpret
+        assert kops.resolve_interpret(None) is interpret
+    assert kops.resolve_interpret(True) is True      # explicit flag wins
+    assert kops.resolve_interpret(False) is False
+
+
+def test_fused_kernel_chunked_grid_matches_single_call(monkeypatch):
+    """The neighbour table is prefetched per chunk of tiles (scalar memory
+    bound); a multi-chunk grid, its last chunk clamped onto the previous
+    one, must give the single-call result exactly."""
+    from repro.core.engine import LBMConfig, SparseTiledLBM
+    from repro.data.geometry import random_spheres
+    from repro.kernels import stream_collide as sc
+
+    g = random_spheres(box=16, porosity=0.6, diameter=8, seed=1)
+    cfg = LBMConfig(collision=C.CollisionConfig(tau=0.7), dtype="float32",
+                    periodic=(True, True, True), u0=(0.01, 0.0, 0.02))
+    eng = SparseTiledLBM(g, cfg)
+    lat = d3q19()
+    fp, types, nbrs = sc.pack_engine_state(eng.tiling, eng.f, lat)
+    t = eng.tiling.num_tiles
+    assert t % 5, "pick a chunk that leaves a partial last chunk"
+    whole = sc.stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
+                                    interpret=True)
+    monkeypatch.setattr(sc, "TILES_PER_CALL", t // 5)
+    chunked = sc.stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(chunked), np.asarray(whole))

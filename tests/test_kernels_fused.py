@@ -38,19 +38,17 @@ def test_fused_kernel_matches_engine_step(model, fluid):
     assert err < 5e-5, err
 
 
+@pytest.mark.usefixtures("x64")
 def test_fused_kernel_preserves_float64():
     """The kernel must compute in the storage dtype (it used to force
     float32, which silently capped the float64 parity tests)."""
-    from jax.experimental import enable_x64
-
-    with enable_x64(True):
-        eng, cfg = _engine(seed=5, p_fluid=0.65)
-        lat = d3q19()
-        fp, types, nbrs = pack_engine_state(
-            eng.tiling, eng.f.astype(jnp.float64), lat)
-        out = stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
-                                   interpret=True)
-        assert out.dtype == jnp.float64
+    eng, cfg = _engine(seed=5, p_fluid=0.65)
+    lat = d3q19()
+    fp, types, nbrs = pack_engine_state(
+        eng.tiling, eng.f.astype(jnp.float64), lat)
+    out = stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
+                               interpret=True)
+    assert out.dtype == jnp.float64
 
 
 def test_fused_kernel_multi_step_and_mass():

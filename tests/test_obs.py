@@ -241,10 +241,11 @@ def test_disabled_mode_identical_jaxpr():
     only attaches metadata, so even fully enabled the jaxpr is identical."""
     eng = _tiny_engine(split_stream=True)
     obs.disable()
-    off = str(jax.make_jaxpr(eng.backend.step)(eng.f))
+    off = str(jax.make_jaxpr(eng.backend.step)(eng.f, eng.backend.tables))
     try:
         obs.enable(metrics=True, trace=True)          # device annotations on
-        on = str(jax.make_jaxpr(eng.backend.step)(eng.f))
+        on = str(jax.make_jaxpr(eng.backend.step)(eng.f,
+                                                  eng.backend.tables))
     finally:
         obs.disable()
     assert on == off

@@ -4,15 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-
-@pytest.fixture(autouse=True)
-def _x64():
-    """True float64 for physics tolerances (engines request float64
-    explicitly; without the flag JAX silently truncates to f32)."""
-    from jax.experimental import enable_x64
-    with enable_x64(True):
-        yield
 from hypothesis import given, settings, strategies as st
 
 from repro.core import collision as C
@@ -21,6 +12,8 @@ from repro.core.engine import LBMConfig, SparseTiledLBM
 from repro.core.dense import DenseLBM
 from repro.core.tiling import INLET, OUTLET, SOLID
 from repro.data.geometry import cavity3d, channel2d, duct, random_spheres
+
+pytestmark = pytest.mark.usefixtures("x64")
 
 LID = 4
 

@@ -19,11 +19,7 @@ from repro.core.tiling import INLET, OUTLET
 from repro.data.geometry import channel2d, duct_wrap, random_spheres
 
 
-@pytest.fixture(autouse=True)
-def _x64():
-    from jax.experimental import enable_x64
-    with enable_x64(True):
-        yield
+pytestmark = pytest.mark.usefixtures("x64")
 
 
 TOL = 1e-12

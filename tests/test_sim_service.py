@@ -14,11 +14,7 @@ from repro.sim.registry import (EngineRegistry, config_from_dict,
 from repro.sim.service import SimService, probe_indices
 
 
-@pytest.fixture(autouse=True)
-def _x64():
-    from jax.experimental import enable_x64
-    with enable_x64(True):
-        yield
+pytestmark = pytest.mark.usefixtures("x64")
 
 
 def _box(n=8):

@@ -294,28 +294,26 @@ def test_split_requires_gather_backend():
 
 # ------------------------------------------------- fused x node_order
 @pytest.mark.parametrize("node_order", NODE_ORDERS)
+@pytest.mark.usefixtures("x64")
 def test_fused_parity_under_node_orders(node_order):
     """Acceptance: the fused kernel keeps 1e-12 float64 parity with the
     monolithic gather backend under every within-tile node order."""
-    from jax.experimental import enable_x64
-
     g = _spheres()
-    with enable_x64(True):
-        base = dict(collision=C.CollisionConfig(tau=0.7), dtype="float64",
-                    periodic=(True, True, True), u0=(0.01, 0.0, 0.02))
-        ref = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
-        eng = SparseTiledLBM(g, LBMConfig(backend="fused",
-                                          node_order=node_order, **base))
-        ref.run(4)
-        eng.run(4)
-        r0, u0 = ref.macroscopics()
-        r1, u1 = eng.macroscopics()
-        d = np.abs(untile(ref.tiling, np.asarray(r0), 0.0)
-                   - untile(eng.tiling, np.asarray(r1), 0.0))
-        du = np.abs(untile(ref.tiling, np.asarray(u0), 0.0)
-                    - untile(eng.tiling, np.asarray(u1), 0.0))
-        assert float(d.max()) < 1e-12
-        assert float(du.max()) < 1e-12
+    base = dict(collision=C.CollisionConfig(tau=0.7), dtype="float64",
+                periodic=(True, True, True), u0=(0.01, 0.0, 0.02))
+    ref = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
+    eng = SparseTiledLBM(g, LBMConfig(backend="fused",
+                                      node_order=node_order, **base))
+    ref.run(4)
+    eng.run(4)
+    r0, u0 = ref.macroscopics()
+    r1, u1 = eng.macroscopics()
+    d = np.abs(untile(ref.tiling, np.asarray(r0), 0.0)
+               - untile(eng.tiling, np.asarray(r1), 0.0))
+    du = np.abs(untile(ref.tiling, np.asarray(u0), 0.0)
+                - untile(eng.tiling, np.asarray(u1), 0.0))
+    assert float(d.max()) < 1e-12
+    assert float(du.max()) < 1e-12
 
 
 # ------------------------------------------- absent boundary type (fix)
@@ -330,20 +328,18 @@ def test_boundary_pass_tables_empty_returns_none():
     assert out is None
 
 
+@pytest.mark.usefixtures("x64")
 def test_fused_skips_pass_for_absent_boundary_type():
     """A geometry whose declared boundary type matches no nodes must run
     (pass skipped), matching the gather backend."""
-    from jax.experimental import enable_x64
-
     g = _spheres()   # spheres pack: FLUID + SOLID only, no INLET nodes
-    with enable_x64(True):
-        base = dict(collision=C.CollisionConfig(tau=0.7), dtype="float64",
-                    periodic=(True, True, True), boundaries=BCS[:1])
-        e_g = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
-        e_f = SparseTiledLBM(g, LBMConfig(backend="fused", **base))
-        assert e_f.backend._bc is None
-        e_g.run(3)
-        e_f.run(3)
-        c_g = np.asarray(e_g.backend.canonical(e_g.f))
-        c_f = np.asarray(e_f.backend.canonical(e_f.f))
-        assert float(np.abs(c_g - c_f).max()) < 1e-12
+    base = dict(collision=C.CollisionConfig(tau=0.7), dtype="float64",
+                periodic=(True, True, True), boundaries=BCS[:1])
+    e_g = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
+    e_f = SparseTiledLBM(g, LBMConfig(backend="fused", **base))
+    assert "bc" not in e_f.backend.tables
+    e_g.run(3)
+    e_f.run(3)
+    c_g = np.asarray(e_g.backend.canonical(e_g.f))
+    c_f = np.asarray(e_f.backend.canonical(e_f.f))
+    assert float(np.abs(c_g - c_f).max()) < 1e-12

@@ -179,34 +179,32 @@ def test_gather_bitwise_identical_across_orders(geom):
     ("spheres", "morton_slab"),
     ("vessel", "hilbert"),           # body-like geometry, NEBB boundaries
 ])
+@pytest.mark.usefixtures("x64")
 def test_fused_parity_across_orders(geom, order):
     """Fused backend under reordering matches zmajor gather to 1e-12, on a
     sparse (spheres) and a body-like (vessel) geometry."""
-    from jax.experimental import enable_x64
-
-    with enable_x64(True):
-        if geom == "spheres":
-            g = _spheres()
-            base = dict(collision=C.CollisionConfig(tau=0.7),
-                        dtype="float64", periodic=(True, True, True),
-                        u0=(0.01, 0.0, 0.02))
-        else:
-            g = vessel_aneurysm((32, 24, 24), radius=7.0, bulge=8.0)
-            base = dict(collision=C.CollisionConfig(tau=0.8),
-                        dtype="float64", boundaries=(
-                            (INLET, BoundarySpec("velocity", (1, 0, 0),
-                                                 velocity=(0.02, 0, 0))),
-                            (OUTLET, BoundarySpec("pressure", (-1, 0, 0),
-                                                 rho=1.0))))
-        ref = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
-        eng = SparseTiledLBM(g, LBMConfig(backend="fused", tile_order=order,
-                                          **base))
-        ref.run(4)
-        eng.run(4)
-        r0, u0 = _dense_fields(ref)
-        r1, u1 = _dense_fields(eng)
-        assert float(np.abs(r0 - r1).max()) < 1e-12
-        assert float(np.abs(u0 - u1).max()) < 1e-12
+    if geom == "spheres":
+        g = _spheres()
+        base = dict(collision=C.CollisionConfig(tau=0.7),
+                    dtype="float64", periodic=(True, True, True),
+                    u0=(0.01, 0.0, 0.02))
+    else:
+        g = vessel_aneurysm((32, 24, 24), radius=7.0, bulge=8.0)
+        base = dict(collision=C.CollisionConfig(tau=0.8),
+                    dtype="float64", boundaries=(
+                        (INLET, BoundarySpec("velocity", (1, 0, 0),
+                                             velocity=(0.02, 0, 0))),
+                        (OUTLET, BoundarySpec("pressure", (-1, 0, 0),
+                                             rho=1.0))))
+    ref = SparseTiledLBM(g, LBMConfig(backend="gather", **base))
+    eng = SparseTiledLBM(g, LBMConfig(backend="fused", tile_order=order,
+                                      **base))
+    ref.run(4)
+    eng.run(4)
+    r0, u0 = _dense_fields(ref)
+    r1, u1 = _dense_fields(eng)
+    assert float(np.abs(r0 - r1).max()) < 1e-12
+    assert float(np.abs(u0 - u1).max()) < 1e-12
 
 
 # ----------------------------------------------------------------- sharding

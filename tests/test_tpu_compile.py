@@ -1,0 +1,134 @@
+"""Compiles for a described TPU v5e (no chip needed): the fused Pallas
+kernel, the fused ensemble step and the gather steps at the ``spheres``
+case's size, and a pin that the compiled step does not grow with the
+geometry (its tables are arguments, not embedded constants).
+
+Only this file describes the topology, inside a fixture, so the test
+workers that never run it never load the TPU compiler library."""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import collision as C
+from repro.core.boundary import BoundarySpec
+from repro.core.engine import LBMConfig, SparseTiledLBM
+from repro.core.lattice import d3q19
+from repro.data.geometry import duct_wrap, random_spheres
+from repro.kernels.stream_collide import stream_collide_tiles
+from repro.core.tiling import INLET, OUTLET
+from repro.launch.lbm import make_case
+
+SPHERES_TILES = 4018         # make_case("spheres", 1): 64^3 pack, p = 0.7
+BCS = ((INLET, BoundarySpec("velocity", (0, 0, 1), velocity=(0, 0, 0.02))),
+       (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_persistent_cache():
+    """A described-device compile is written to the persistent cache but
+    cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _engine(geometry, boundaries=BCS, **kw):
+    cfg = LBMConfig(collision=C.CollisionConfig(tau=0.6), dtype="float32",
+                    boundaries=boundaries, kernel_interpret=False, **kw)
+    return SparseTiledLBM(geometry, cfg)
+
+
+@pytest.fixture(scope="module")
+def spheres():
+    return make_case("spheres").geometry
+
+
+def _compile_step(eng, sharding, ensemble: int = 0):
+    """Compile the engine's jitted step (or ensemble step) for the chip."""
+    b = eng.backend
+    if ensemble:
+        f = b.ensemble_state(eng.f, ensemble)
+        fn, tab = b.ensemble_step, b.ensemble_tables(ensemble)
+    else:
+        f, fn, tab = eng.f, b.step, b.tables
+    return jax.jit(fn).lower(_shapes(f, sharding),
+                             _shapes(tab, sharding)).compile()
+
+
+@pytest.mark.parametrize("model", ["lbgk", "lbmrt"])
+def test_fused_kernel_compiles(one_chip, model):
+    t = SPHERES_TILES
+    lat = d3q19()
+    cfg = C.CollisionConfig(model=model, tau=0.7)
+    f = jax.ShapeDtypeStruct((t + 1, lat.q, 64), jnp.float32,
+                             sharding=one_chip)
+    types = jax.ShapeDtypeStruct((t + 1, 1, 64), jnp.int32,
+                                 sharding=one_chip)
+    nbrs = jax.ShapeDtypeStruct((t, 27), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda f, ty, nb: stream_collide_tiles(
+        f, ty, nb, lat, cfg, interpret=False)).lower(f, types, nbrs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_ensemble_step_compiles(one_chip, spheres):
+    eng = _engine(spheres, backend="fused")
+    assert eng.tiling.num_tiles == SPHERES_TILES
+    compiled = _compile_step(eng, one_chip, ensemble=2)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("split", [False, True], ids=["mono", "split"])
+def test_gather_step_compiles(one_chip, spheres, split):
+    eng = _engine(spheres, backend="gather", split_stream=split)
+    compiled = _compile_step(eng, one_chip)
+    assert compiled.memory_analysis().argument_size_in_bytes > eng.f.nbytes
+
+
+@pytest.mark.parametrize("backend", ["gather", "fused"])
+def test_compiled_step_size_flat_in_geometry(one_chip, spheres, backend):
+    """Growing the domain 3x must not grow the compiled program with it:
+    every geometry-sized table is a step argument.  (Closed over, the
+    gather table alone made the program larger than the state.)  XLA's
+    own code for the larger shapes may differ by a few MB."""
+    sizes = []
+    for g in (spheres, duct_wrap(random_spheres(box=96, porosity=0.7,
+                                                diameter=16))):
+        eng = _engine(g, backend=backend)
+        mem = _compile_step(eng, one_chip).memory_analysis()
+        sizes.append((eng.tiling.num_tiles, mem.generated_code_size_in_bytes,
+                      mem.argument_size_in_bytes))
+    (t0, code0, args0), (t1, code1, args1) = sizes
+    assert t1 > 3 * t0 and args1 > 3 * args0, sizes
+    assert code1 <= 1.5 * code0, sizes
+    assert code1 - code0 < (args1 - args0) / 10, sizes
