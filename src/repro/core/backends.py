@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.obs.trace import phase_scope
 
 from . import collision as col
@@ -70,6 +71,18 @@ def make_backend(name: str, cfg, lat, tiling: Tiling, tables: StreamTables,
     if name == "fused":
         return FusedBackend(cfg, lat, tiling, tables, interpret)
     raise ValueError(f"unknown backend {name!r}; expected one of {BACKENDS}")
+
+
+def place(host):
+    """A tree of host tables on the device, under ``lbm.setup.place``
+    (waited for only when the recorder is enabled, so that the span then
+    times the transfer and not its dispatch)."""
+    tr = obs.get_tracer()
+    with tr.span("lbm.setup.place"):
+        tree = jax.tree.map(jnp.asarray, host)
+        if tr.enabled:
+            jax.block_until_ready(tree)
+    return tree
 
 
 def boundary_pass_tables(node_types: np.ndarray, gather_idx: np.ndarray,
@@ -184,30 +197,30 @@ class GatherBackend:
                  interpret: bool):
         self.cfg, self.lat, self.tiling, self.stream = cfg, lat, tiling, tables
         self.interpret = interpret
-        types = tiling.node_types                            # (T, n) canonical
-        self._solid = jnp.asarray(types == SOLID)
         self._bc_specs = tuple(spec for _, spec in cfg.boundaries)
-        self.tables = {
-            "solid": self._solid,
-            "bc_masks": tuple(jnp.asarray(types == tv)
-                              for tv, _ in cfg.boundaries),
-        }
-        if cfg.split_stream:
-            sp = tables.split
-            self.tables["split"] = {
-                "intra": jnp.asarray(sp.intra_idx),
-                "case": jnp.asarray(sp.case.astype(np.int32)),
-                "is_cross": jnp.asarray(sp.is_cross),
-                "nbr": jnp.asarray(sp.nbr),
-                "bounce_dst": jnp.asarray(sp.bounce_dst),
-                "irregular_dst": jnp.asarray(sp.irregular_dst),
-                "irregular_src": jnp.asarray(sp.irregular_src),
-                "opp": jnp.asarray(sp.opp),
-                "perms": jnp.asarray(tables.perms),
+        with obs.get_tracer().span("lbm.setup.backend_tables"):
+            types = tiling.node_types                        # (T, n) canonical
+            host = {
+                "solid": types == SOLID,
+                "bc_masks": tuple(types == tv for tv, _ in cfg.boundaries),
             }
-        else:
-            self.tables["gather"] = jnp.asarray(
-                tables.gather_idx.reshape(lat.q, -1))
+            if cfg.split_stream:
+                sp = tables.split
+                host["split"] = {
+                    "intra": sp.intra_idx,
+                    "case": sp.case.astype(np.int32),
+                    "is_cross": sp.is_cross,
+                    "nbr": sp.nbr,
+                    "bounce_dst": sp.bounce_dst,
+                    "irregular_dst": sp.irregular_dst,
+                    "irregular_src": sp.irregular_src,
+                    "opp": sp.opp,
+                    "perms": tables.perms,
+                }
+            else:
+                host["gather"] = tables.gather_idx.reshape(lat.q, -1)
+        self.tables = place(host)
+        self._solid = self.tables["solid"]
 
     # ------------------------------------------------- layout shuffles
     def to_storage(self, f_canon: jnp.ndarray) -> jnp.ndarray:
@@ -317,6 +330,12 @@ class FusedBackend:
     state via a precomputed packed-layout gather, applies the boundary
     rebuild + collision there, and scatters those tiles over the kernel
     output.
+
+    Every device op of :meth:`step` sits under one named scope: the
+    kernel (``%stream_collide`` in a device trace) under
+    ``lbm.phase.stream_collide``, its output buffer and the scratch-row
+    reset under ``lbm.phase.pack``, the NEBB pass under
+    ``lbm.phase.boundary``.
     """
 
     name = "fused"
@@ -332,16 +351,18 @@ class FusedBackend:
                 f"layout_scheme must be 'xyz' (got {cfg.layout_scheme!r})")
         self.cfg, self.lat, self.tiling = cfg, lat, tiling
         self.interpret = interpret
-        self._types_np = kernel_node_types(tiling.node_types)  # (T+1, 1, n)
-        self._nbrs_np = build_neighbor_table(tiling, cfg.periodic)
-        self._solid = jnp.asarray(tiling.node_types == SOLID)
-        self._bc_np = (boundary_pass_tables(
-            tiling.node_types, tables.gather_idx, cfg.boundaries, lat.q,
-            tiling.nodes_per_tile)
-            if cfg.boundaries and cfg.kernel_mode == "full" else None)
         self._bc_specs = tuple(spec for _, spec in cfg.boundaries)
-        self._ens_tables: dict[int, dict] = {}
-        self.tables = self.ensemble_tables(1)
+        with obs.get_tracer().span("lbm.setup.backend_tables"):
+            self._types_np = kernel_node_types(tiling.node_types)  # (T+1,1,n)
+            self._nbrs_np = build_neighbor_table(tiling, cfg.periodic)
+            self._bc_np = (boundary_pass_tables(
+                tiling.node_types, tables.gather_idx, cfg.boundaries, lat.q,
+                tiling.nodes_per_tile)
+                if cfg.boundaries and cfg.kernel_mode == "full" else None)
+            host = self._host_tables(1)
+        self._solid, self.tables = place(
+            (tiling.node_types == SOLID, host))
+        self._ens_tables: dict[int, dict] = {1: self.tables}
 
     # ------------------------------------------------------------ state
     def initial_state(self, feq_canon: jnp.ndarray) -> jnp.ndarray:
@@ -362,11 +383,11 @@ class FusedBackend:
         from repro.kernels.stream_collide import stream_collide_tiles
 
         cfg = self.cfg
-        with phase_scope("lbm.phase.stream_collide"):
-            out = stream_collide_tiles(
-                f, tab["types"], tab["nbrs"], self.lat, cfg.collision,
-                a=cfg.a, force=cfg.force, interpret=self.interpret,
-                mode=cfg.kernel_mode, node_order=cfg.node_order)
+        # scoped inside: lbm.phase.stream_collide, lbm.phase.pack
+        out = stream_collide_tiles(
+            f, tab["types"], tab["nbrs"], self.lat, cfg.collision,
+            a=cfg.a, force=cfg.force, interpret=self.interpret,
+            mode=cfg.kernel_mode, node_order=cfg.node_order)
         if "bc" in tab:
             bc = tab["bc"]
             out = nebb_boundary_pass(
@@ -389,8 +410,14 @@ class FusedBackend:
         ``b * T * Q * n``, so :func:`nebb_boundary_pass` runs unmodified
         over all replicas' boundary tiles in one pass.
         """
-        if batch in self._ens_tables:
-            return self._ens_tables[batch]
+        if batch not in self._ens_tables:
+            with obs.get_tracer().span("lbm.setup.backend_tables"):
+                host = self._host_tables(batch)
+            self._ens_tables[batch] = place(host)
+        return self._ens_tables[batch]
+
+    def _host_tables(self, batch: int) -> dict:
+        """The numpy tables :meth:`ensemble_tables` places."""
         t, n = self.tiling.num_tiles, self.tiling.nodes_per_tile
         q = self.lat.q
         nbrs = np.concatenate(
@@ -398,19 +425,17 @@ class FusedBackend:
              for b in range(batch)]).astype(np.int32)
         types = np.concatenate([self._types_np[:t]] * batch
                                + [self._types_np[t:]])
-        tab = {"types": jnp.asarray(types), "nbrs": jnp.asarray(nbrs)}
+        tab = {"types": types, "nbrs": nbrs}
         if self._bc_np is not None:
             bt, packed, type_masks, solid_b = self._bc_np
             tab["bc"] = {
-                "tiles": jnp.asarray(np.concatenate(
-                    [bt + b * t for b in range(batch)]).astype(np.int32)),
-                "gather": jnp.asarray(np.concatenate(
-                    [packed + b * t * q * n for b in range(batch)], axis=1)),
-                "type_masks": jnp.asarray(
-                    np.concatenate([type_masks] * batch, axis=1)),
-                "solid": jnp.asarray(np.concatenate([solid_b] * batch)),
+                "tiles": np.concatenate(
+                    [bt + b * t for b in range(batch)]).astype(np.int32),
+                "gather": np.concatenate(
+                    [packed + b * t * q * n for b in range(batch)], axis=1),
+                "type_masks": np.concatenate([type_masks] * batch, axis=1),
+                "solid": np.concatenate([solid_b] * batch),
             }
-        self._ens_tables[batch] = tab
         return tab
 
     def ensemble_state(self, f_single: jnp.ndarray, batch: int) -> jnp.ndarray:

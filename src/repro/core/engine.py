@@ -101,21 +101,29 @@ class SparseTiledLBM:
                 "tables)")
         self.cfg = cfg
         self.lat = get_lattice(cfg.lattice)
-        self.tiling: Tiling = tile_geometry(node_type, cfg.a,
-                                            order=cfg.tile_order,
-                                            node_order=cfg.node_order)
-        self.tables = build_stream_tables(
-            self.tiling, self.lat, cfg.layout_scheme, cfg.periodic,
-            split=cfg.split_stream,
-        )
         self.dtype = jnp.dtype(cfg.dtype)
         self.kernel_interpret = resolve_interpret(cfg.kernel_interpret)
-
-        self.backend = make_backend(cfg.backend, cfg, self.lat, self.tiling,
-                                    self.tables, self.kernel_interpret)
-        self._solid = self.backend._solid                    # (T, n) canonical
-
-        self.f = self.backend.initial_state(self._initial_feq())
+        # set-up spans: the backend adds lbm.setup.backend_tables and
+        # lbm.setup.place; placements are awaited only when recording
+        tr = obs.get_tracer()
+        with tr.span("lbm.setup", backend=cfg.backend):
+            with tr.span("lbm.setup.tiling"):
+                self.tiling: Tiling = tile_geometry(
+                    node_type, cfg.a, order=cfg.tile_order,
+                    node_order=cfg.node_order)
+            with tr.span("lbm.setup.stream_tables"):
+                self.tables = build_stream_tables(
+                    self.tiling, self.lat, cfg.layout_scheme, cfg.periodic,
+                    split=cfg.split_stream,
+                )
+            self.backend = make_backend(cfg.backend, cfg, self.lat,
+                                        self.tiling, self.tables,
+                                        self.kernel_interpret)
+            self._solid = self.backend._solid                # (T, n) canonical
+            with tr.span("lbm.setup.initial_state"):
+                self.f = self.backend.initial_state(self._initial_feq())
+                if tr.enabled:
+                    jax.block_until_ready(self.f)
         self._step_fn = jax.jit(self.backend.step, donate_argnums=0)
         self._multi_cache: dict[int, callable] = {}
 
@@ -161,19 +169,23 @@ class SparseTiledLBM:
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)
 
-    def run(self, steps: int) -> None:
-        """Run ``steps`` iterations inside a single jitted fori_loop."""
+    def run_fn(self, steps: int):
+        """The jitted ``(f, tables) -> f`` program :meth:`run` calls:
+        ``steps`` iterations inside one fori_loop, ``f`` donated."""
         if steps not in self._multi_cache:
-            fn = jax.jit(
+            self._multi_cache[steps] = jax.jit(
                 lambda f, tab: jax.lax.fori_loop(
                     0, steps, lambda i, x: self.backend.step(x, tab), f
                 ),
                 donate_argnums=0,
             )
-            self._multi_cache[steps] = fn
-        tr = obs.get_tracer()
-        with tr.span("lbm.run", steps=steps), obs.annotation("lbm.run"):
-            self.f = self._multi_cache[steps](self.f, self.backend.tables)
+        return self._multi_cache[steps]
+
+    def run(self, steps: int) -> None:
+        """Run ``steps`` iterations inside a single jitted fori_loop."""
+        fn = self.run_fn(steps)
+        with obs.get_tracer().span("lbm.run", steps=steps):
+            self.f = fn(self.f, self.backend.tables)
         reg = obs.get_metrics()
         if reg.enabled:
             reg.counter("lbm.step_total").inc(steps)

@@ -244,45 +244,63 @@ class ShardedLBM:
                             [names.index(a) for a in order])
         self.mesh = Mesh(devs.reshape(n_slab, -1), ("slab", "repl"))
 
-        self.plan = make_slab_plan(node_type, cfg.a, n_slab,
-                                   periodic_z=cfg.periodic[2],
-                                   tile_order=cfg.tile_order,
-                                   node_order=cfg.node_order)
-        self._build_tables()
-        self._build_step()
-        self.f = None
-        if not dryrun:
-            self._tbl = {
-                k: jax.device_put(v, NamedSharding(self.mesh,
-                                                   self._tbl_specs[k]))
-                for k, v in self._tbl_np.items()}
-            self.f = jax.device_put(self._initial_state(), self._f_sharding)
+        # the same set-up spans as SparseTiledLBM; placements are awaited
+        # only when recording
+        tr = obs.get_tracer()
+        with tr.span("lbm.setup", backend=cfg.backend, sharded=True):
+            with tr.span("lbm.setup.tiling"):
+                self.plan = make_slab_plan(node_type, cfg.a, n_slab,
+                                           periodic_z=cfg.periodic[2],
+                                           tile_order=cfg.tile_order,
+                                           node_order=cfg.node_order)
+            self._build_tables()
+            self._build_step()
+            self.f = None
+            if not dryrun:
+                with tr.span("lbm.setup.place"):
+                    self._tbl = {
+                        k: jax.device_put(v, NamedSharding(
+                            self.mesh, self._tbl_specs[k]))
+                        for k, v in self._tbl_np.items()}
+                    if tr.enabled:
+                        jax.block_until_ready(self._tbl)
+                with tr.span("lbm.setup.initial_state"):
+                    self.f = jax.device_put(self._initial_state(),
+                                            self._f_sharding)
+                    if tr.enabled:
+                        jax.block_until_ready(self.f)
         self._multi_cache: dict[int, callable] = {}
 
     # ------------------------------------------------------------- tables
     def _build_tables(self) -> None:
-        cfg, lat, plan = self.cfg, self.lat, self.plan
-        q, tp, n = lat.q, plan.t_pad, plan.nodes_per_tile
-        d_cnt = plan.n_dev
-        wrap = plan.periodic_z and d_cnt > 1
+        cfg, plan = self.cfg, self.plan
         # periodic z is carried by the wrapped halo when sharded; a single
         # slab keeps the engine's in-table wrap
-        local_pz = cfg.periodic[2] and d_cnt == 1
+        local_pz = cfg.periodic[2] and plan.n_dev == 1
         periodic = (cfg.periodic[0], cfg.periodic[1], local_pz)
+        tr = obs.get_tracer()
+        with tr.span("lbm.setup.stream_tables"):
+            tabs_of_dev = [build_stream_tables(lt, self.lat,
+                                               cfg.layout_scheme, periodic,
+                                               split=cfg.split_stream)
+                           for lt in plan.local_tilings]
+        with tr.span("lbm.setup.backend_tables"):
+            self._build_backend_tables(tabs_of_dev, periodic)
 
+    def _build_backend_tables(self, tabs_of_dev, periodic) -> None:
+        """Per-slab step tables (numpy) from the slabs' stream tables."""
+        cfg, plan = self.cfg, self.plan
+        q, tp, n = self.lat.q, plan.t_pad, plan.nodes_per_tile
+        d_cnt = plan.n_dev
+        wrap = plan.periodic_z and d_cnt > 1
         gather = np.empty((d_cnt, q, tp, n), np.int32)
         solid = np.ones((d_cnt, tp, n), bool)
         types = np.zeros((d_cnt, tp, n), np.uint8)
-        tabs_of_dev = []
-        self._perms = None
+        # layout perms are device-independent
+        self._perms = tabs_of_dev[0].perms
+        self._inv_perms = tabs_of_dev[0].inv_perms
         frac_w, fracs = [], []
-        for d, lt in enumerate(plan.local_tilings):
-            tabs = build_stream_tables(lt, lat, cfg.layout_scheme, periodic,
-                                       split=cfg.split_stream)
-            tabs_of_dev.append(tabs)
-            if self._perms is None:     # layout perms are device-independent
-                self._perms = tabs.perms
-                self._inv_perms = tabs.inv_perms
+        for d, (lt, tabs) in enumerate(zip(plan.local_tilings, tabs_of_dev)):
             t_loc = lt.num_tiles
             g = tabs.gather_idx.astype(np.int64)
             m_loc, m_pad = t_loc * n, tp * n
@@ -590,11 +608,11 @@ class ShardedLBM:
                     rd, rdm = tbl["rd"][0], tbl["rdm"][0]
                     f = f.at[ru].set(jnp.where(rum[:, None, None], up, f[ru]))
                     f = f.at[rd].set(jnp.where(rdm[:, None, None], dn, f[rd]))
-            with obs.phase_scope("lbm.phase.stream_collide"):
-                out = stream_collide_tiles(
-                    f, tbl["types"][0], tbl["nbrs"][0], lat, cfg.collision,
-                    a=cfg.a, force=cfg.force, interpret=self.kernel_interpret,
-                    mode=cfg.kernel_mode, node_order=cfg.node_order)
+            # scoped inside: lbm.phase.stream_collide, lbm.phase.pack
+            out = stream_collide_tiles(
+                f, tbl["types"][0], tbl["nbrs"][0], lat, cfg.collision,
+                a=cfg.a, force=cfg.force, interpret=self.kernel_interpret,
+                mode=cfg.kernel_mode, node_order=cfg.node_order)
             if "bcg" in tbl:
                 # masked NEBB pass (shared with FusedBackend): re-stream +
                 # rebuild + collide ONLY the boundary tiles, pre-step state
@@ -603,7 +621,9 @@ class ShardedLBM:
                     tuple(spec for _, spec in cfg.boundaries),
                     tbl["bct"][0], tbl["bcg"][0], tbl["bcm"][:, 0],
                     tbl["bcs"][0])
-                out = zero_scratch_row(out, tp - 1)  # padded rows hit dummy
+                with obs.phase_scope("lbm.phase.pack"):
+                    # padded rows hit the dummy
+                    out = zero_scratch_row(out, tp - 1)
             return out[None]
 
         body = body_fused if self.fused else body_gather
@@ -634,9 +654,7 @@ class ShardedLBM:
                 lambda f, tbl: jax.lax.fori_loop(
                     0, steps, lambda i, x: self._raw_step(x, tbl), f),
                 donate_argnums=0)
-        tr = obs.get_tracer()
-        with tr.span("lbm.run", steps=steps, sharded=True), \
-                obs.annotation("lbm.run"):
+        with obs.get_tracer().span("lbm.run", steps=steps, sharded=True):
             self.f = self._multi_cache[steps](self.f, self._tbl)
         self._record_steps(steps)
 

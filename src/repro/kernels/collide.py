@@ -162,6 +162,7 @@ def collide_pallas(
             out_specs=o_spec,
             out_shape=out_shape,
             interpret=interpret,
+            name="collide_lbgk",
         )(f, solid_u8)
 
     a_mat = jnp.asarray(col.collision_matrix_np(lat, cfg.tau), f.dtype)
@@ -174,4 +175,5 @@ def collide_pallas(
         out_specs=o_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="collide_lbmrt",
     )(f, solid_u8, a_mat)

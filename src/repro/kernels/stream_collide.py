@@ -48,6 +48,7 @@ from repro.core import collision as col
 from repro.core.lattice import Lattice
 from repro.core.tiling import (NEIGHBOR_OFFSETS, SOLID, Tiling,
                                neighbor_offset_index)
+from repro.obs.trace import phase_scope
 
 from .collide import _equilibrium_rows
 
@@ -252,6 +253,11 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
                 remapped to match
     interpret:  None = auto (:func:`repro.kernels.ops.default_interpret`)
     Returns the post-step (T+1, Q, n) (scratch row zeroed).
+
+    The Pallas calls are named ``stream_collide`` (``rw_only`` for that
+    mode), so a device trace names the kernel's instructions; they run
+    under the named scope ``lbm.phase.stream_collide``, and the output
+    buffer and scratch-row reset around them under ``lbm.phase.pack``.
     """
     from .ops import resolve_interpret
 
@@ -261,15 +267,18 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
     t = t1 - 1
 
     if mode == "rw_only":
-        out = pl.pallas_call(
-            _rw_kernel,
-            grid=(t,),
-            in_specs=[pl.BlockSpec((1, q, n), lambda i: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, q, n), lambda i: (i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((t1, q, n), f.dtype),
-            interpret=interpret,
-        )(f)
-        return zero_scratch_row(out, t)
+        with phase_scope("lbm.phase.stream_collide"):
+            out = pl.pallas_call(
+                _rw_kernel,
+                grid=(t,),
+                in_specs=[pl.BlockSpec((1, q, n), lambda i: (i, 0, 0))],
+                out_specs=pl.BlockSpec((1, q, n), lambda i: (i, 0, 0)),
+                out_shape=jax.ShapeDtypeStruct((t1, q, n), f.dtype),
+                interpret=interpret,
+                name="rw_only",
+            )(f)
+        with phase_scope("lbm.phase.pack"):
+            return zero_scratch_row(out, t)
 
     offsets, perms_np, cases_np = _pull_geometry(lat, a, node_order)
     kernel = make_kernel(lat, cfg, len(offsets), force, mode)
@@ -326,6 +335,7 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
         out_shape=jax.ShapeDtypeStruct((t1, q, n), f.dtype),
         input_output_aliases={2 + len(operands): 0},
         interpret=interpret,
+        name="stream_collide",
     )
     nb_flat = neighbors.reshape(-1)
 
@@ -334,8 +344,12 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
         nb = jax.lax.dynamic_slice_in_dim(nb_flat, start * nw, chunk * nw)
         return call(start[None], nb, *operands, out)
 
-    out = jax.lax.fori_loop(0, n_chunks, run_chunk, jnp.zeros_like(f))
-    return zero_scratch_row(out, t)
+    with phase_scope("lbm.phase.pack"):
+        out = jnp.zeros_like(f)
+    with phase_scope("lbm.phase.stream_collide"):
+        out = jax.lax.fori_loop(0, n_chunks, run_chunk, out)
+    with phase_scope("lbm.phase.pack"):
+        return zero_scratch_row(out, t)
 
 
 def kernel_node_types(node_types: np.ndarray) -> np.ndarray:

@@ -216,7 +216,6 @@ def run_local(args):
     eng.run(args.steps)  # compile the fori_loop + warm
     jax.block_until_ready(eng.f)
     eng.reset()          # back to t=0: the timed run IS the reported physics
-    obs.get_tracer().reset()       # drop warmup spans from the trace
     t0 = time.time()
     eng.run(args.steps)  # timed: one dispatch for the whole loop
     jax.block_until_ready(eng.f)
@@ -271,13 +270,14 @@ def main(argv=None):
                     help="write the obs metric registry as JSONL here")
     ap.add_argument("--trace", default=None,
                     help="write a Chrome-trace JSON (perfetto-loadable) "
-                         "here; also enables jax named-scope phase names")
+                         "here: the set-up spans, the warm-up (compiling) "
+                         "run and the timed run")
     args = ap.parse_args(argv)
     init_compile_cache()
 
     if args.metrics_out or args.trace:
-        # enable BEFORE any engine is built so named scopes reach the
-        # traced step and construction spans are captured
+        # enable BEFORE any engine is built so its set-up spans are
+        # recorded
         obs.enable(metrics=True, trace=bool(args.trace))
 
     if not args.dryrun:

@@ -138,7 +138,7 @@ def main(argv=None):
     ap.add_argument("--out", default=None)
     ap.add_argument("--metrics-out", default=None, dest="metrics_out",
                     help="write the obs metric registry as JSONL here "
-                         "(per-tenant counters, aggregate MFLUPS, "
+                         "(per-tenant counters, slot occupancy, "
                          "modelled bandwidth fractions per group)")
     ap.add_argument("--trace", default=None,
                     help="write a Chrome-trace JSON (perfetto-loadable) "

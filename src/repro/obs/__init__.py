@@ -5,14 +5,13 @@ Public API (everything else is implementation detail):
 * :func:`get_metrics` / :func:`get_tracer` — the process-global
   :class:`~repro.obs.metrics.MetricRegistry` and
   :class:`~repro.obs.trace.SpanRecorder`.  Both start **disabled**: every
-  ``inc``/``set``/``observe``/``span`` call on a disabled instance is an
-  early-return no-op, so instrumented library code costs one attribute
-  check when observability is off (and nothing obs-related ever runs
-  inside jit, so compiled graphs are identical — see ``tests/test_obs.py``).
-* :func:`enable` / :func:`disable` — flip the global switches.
-  ``enable(trace=True)`` also turns on device annotations
-  (``jax.named_scope`` phase names in XLA profiles) unless overridden
-  with ``device_annotations=False``.
+  ``inc``/``set``/``observe`` call on a disabled registry is an
+  early-return no-op, and a disabled recorder's ``span`` is only the
+  profiler annotation of its name (a no-op unless a profiler runs).
+* :func:`phase_scope` — ``jax.named_scope`` around a traced phase of a
+  step; always on, since a scope is HLO metadata and changes no compiled
+  instruction (``tests/test_obs.py`` pins the names in the lowered step).
+* :func:`enable` / :func:`disable` — flip the two collectors.
 * :func:`use` — context manager that swaps in caller-owned registry /
   recorder instances (and restores the previous ones on exit), so
   ``benchmarks.common.timed_mflups`` and tests can collect into private
@@ -34,9 +33,7 @@ import contextlib
 
 from repro.obs.metrics import (CATALOGUE, Counter, Gauge, Histogram,
                                MetricRegistry)
-from repro.obs.trace import (Span, SpanRecorder, annotation,
-                             device_annotations_enabled, phase_scope,
-                             set_device_annotations)
+from repro.obs.trace import Span, SpanRecorder, phase_scope
 
 _metrics = MetricRegistry(enabled=False)
 _tracer = SpanRecorder(enabled=False)
@@ -50,21 +47,16 @@ def get_tracer() -> SpanRecorder:
     return _tracer
 
 
-def enable(metrics: bool = True, trace: bool = True,
-           device_annotations: bool | None = None) -> None:
-    """Turn the global collectors on.  ``device_annotations`` defaults to
-    following ``trace``; enable it BEFORE building engines (named scopes
-    are applied at trace time and cached compilations won't gain them)."""
+def enable(metrics: bool = True, trace: bool = True) -> None:
+    """Turn the global collectors on (enable before building an engine to
+    record its ``lbm.setup`` spans)."""
     _metrics.enabled = metrics
     _tracer.enabled = trace
-    set_device_annotations(
-        trace if device_annotations is None else device_annotations)
 
 
 def disable() -> None:
     _metrics.enabled = False
     _tracer.enabled = False
-    set_device_annotations(False)
 
 
 @contextlib.contextmanager
@@ -93,7 +85,6 @@ def use(metrics: MetricRegistry | None = None,
 
 __all__ = [
     "CATALOGUE", "Counter", "Gauge", "Histogram", "MetricRegistry",
-    "Span", "SpanRecorder", "annotation", "device_annotations_enabled",
-    "disable", "enable", "get_metrics", "get_tracer", "phase_scope",
-    "set_device_annotations", "use",
+    "Span", "SpanRecorder", "disable", "enable", "get_metrics",
+    "get_tracer", "phase_scope", "use",
 ]

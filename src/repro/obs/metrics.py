@@ -67,8 +67,6 @@ CATALOGUE = {
     "sim.session.queue_wait_steps": "histogram: service steps a session "
                                     "waited in queue before seating",
     "sim.slot.occupancy": "gauge: occupied/total slots (per group)",
-    "sim.service.window_mflups": "gauge: aggregate MFLUPS over the last "
-                                 "service step window",
     "sim.node_updates_total": "counter: fluid-node updates served",
     # ---- distributed layer --------------------------------------------
     "dist.halo.bytes": "gauge: halo-exchange bytes per step (all devices)",

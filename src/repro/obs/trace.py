@@ -1,72 +1,45 @@
 """Span-based tracing that exports Chrome-trace-event JSON.
 
-Two complementary mechanisms, both behind one switch each:
+Two mechanisms, both always on where they cost nothing:
 
-* **Host spans** (:class:`SpanRecorder`) — a pure-Python recorder.
-  ``with rec.span("sim.service.step"):`` measures wall time with
+* **Named scopes** (:func:`phase_scope`) — ``jax.named_scope`` around the
+  traced phase bodies of a step (``lbm.phase.stream_collide``,
+  ``lbm.phase.boundary``, ``lbm.phase.pack``, ...).  A scope is HLO
+  metadata (each op's ``op_name``); it changes no compiled instruction,
+  so every compiled program carries the phase names.
+
+* **Host spans** (:class:`SpanRecorder`) — ``with rec.span("lbm.run"):``
+  always enters ``jax.profiler.TraceAnnotation(name)``, a no-op unless a
+  profiler is running, so the span shows in any ``jax.profiler`` trace on
+  the device's clock.  An ENABLED recorder also measures wall time with
   ``time.perf_counter_ns`` and remembers the parent span (a thread-local
   stack, so ``CheckpointStore.save_async``'s background thread nests
   correctly).  ``chrome_trace()`` emits the Chrome trace-event format
   (``ph: "X"`` complete events, microsecond timestamps), which loads
   directly in https://ui.perfetto.dev or chrome://tracing.
 
-* **Device annotations** (:func:`phase_scope` / :func:`annotation`) —
-  when enabled, device work is wrapped in ``jax.named_scope`` (names the
-  XLA ops, visible in compiler dumps/profiles) and host dispatch in
-  ``jax.profiler.TraceAnnotation`` (names show up in ``jax.profiler``
-  traces).  ``named_scope`` only attaches metadata to traced ops — the
-  jaxpr equations are unchanged (pinned by ``tests/test_obs.py``) — but
-  the default is OFF so the disabled path traces byte-identical graphs.
-
 Host spans measure *dispatch* boundaries: inside one jitted step the
-phases fuse, so per-phase device time attribution comes from the XLA
-profile (via the annotations), not from host spans.  Host spans still
-give the serving-layer picture (service step > group step > ensemble
-step > checkpoint save) that the XLA profile cannot see.
+phases fuse, so per-phase device time comes from the device trace (the
+kernels' own names begin their ops' text; the named scopes are in the
+ops' HLO metadata), not from host spans.  Host
+spans give what the device trace cannot see: set-up (``lbm.setup`` and its
+children) and the serving hierarchy (service step > group step > ensemble
+step > checkpoint save).
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 from dataclasses import dataclass, field
 
-_NULL = contextlib.nullcontext()
-
-# Module-level switch for jax.named_scope / TraceAnnotation wrapping.
-# Checked at TRACE time (phase_scope runs while jax traces the step), so
-# flipping it after a function is compiled has no effect on that cache
-# entry — enable it before building the engine.
-_DEVICE_ANNOTATIONS = False
-
-
-def set_device_annotations(on: bool) -> None:
-    global _DEVICE_ANNOTATIONS
-    _DEVICE_ANNOTATIONS = bool(on)
-
-
-def device_annotations_enabled() -> bool:
-    return _DEVICE_ANNOTATIONS
+import jax
 
 
 def phase_scope(name: str):
-    """``jax.named_scope(name)`` when device annotations are on, else a
-    no-op context.  Wrap the *traced* phase bodies with this."""
-    if not _DEVICE_ANNOTATIONS:
-        return _NULL
-    import jax
+    """``jax.named_scope(name)``: wrap the *traced* phase bodies with it."""
     return jax.named_scope(name)
-
-
-def annotation(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` when device annotations are
-    on, else a no-op context.  Wrap *dispatch* sites (outside jit)."""
-    if not _DEVICE_ANNOTATIONS:
-        return _NULL
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 @dataclass
@@ -85,12 +58,15 @@ class Span:
 
 
 class _SpanCtx:
-    __slots__ = ("_rec", "_name", "_attrs", "_sid", "_parent", "_t0")
+    __slots__ = ("_rec", "_name", "_attrs", "_sid", "_parent", "_t0",
+                 "_ann")
 
     def __init__(self, rec: "SpanRecorder", name: str, attrs: dict):
         self._rec, self._name, self._attrs = rec, name, attrs
 
     def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
         rec = self._rec
         stack = rec._stack()
         self._parent = stack[-1] if stack else -1
@@ -109,13 +85,14 @@ class _SpanCtx:
             rec.spans.append(Span(self._sid, self._parent, self._name,
                                   self._t0, dur,
                                   threading.get_ident(), self._attrs))
+        self._ann.__exit__(*exc)
         return False
 
 
 class SpanRecorder:
     """Collects :class:`Span`s; thread-safe (checkpoint saves run on a
-    background thread).  Disabled recorders hand out a shared null
-    context — zero allocation on the hot path."""
+    background thread).  A disabled recorder records nothing and hands out
+    the bare profiler annotation."""
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
@@ -131,8 +108,10 @@ class SpanRecorder:
         return st
 
     def span(self, name: str, **attrs):
+        """Context manager: a profiler annotation ``name``, recorded here
+        with ``attrs`` when the recorder is enabled."""
         if not self.enabled:
-            return _NULL
+            return jax.profiler.TraceAnnotation(name)
         return _SpanCtx(self, name, attrs)
 
     # ----------------------------------------------------------- reads
@@ -183,5 +162,4 @@ class SpanRecorder:
         return path
 
 
-__all__ = ["Span", "SpanRecorder", "annotation", "phase_scope",
-           "set_device_annotations", "device_annotations_enabled"]
+__all__ = ["Span", "SpanRecorder", "phase_scope"]

@@ -103,9 +103,8 @@ class EnsembleLBM:
                 donate_argnums=0,
             )
             self._multi_cache[steps] = fn
-        tr = obs.get_tracer()
-        with tr.span("lbm.ensemble.run", batch=self.batch, steps=steps), \
-                obs.annotation("lbm.ensemble.run"):
+        with obs.get_tracer().span("lbm.ensemble.run", batch=self.batch,
+                                   steps=steps):
             self.f = self._multi_cache[steps](self.f, self._tables)
         reg = obs.get_metrics()
         if reg.enabled:

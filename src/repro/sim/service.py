@@ -25,9 +25,7 @@ it left off — and a torn save (no COMMITTED) is skipped by
 from __future__ import annotations
 
 import dataclasses
-import time
 
-import jax
 import numpy as np
 
 from repro import obs
@@ -212,8 +210,6 @@ class SimService:
         tr = obs.get_tracer()
         progressed = False
         updates = 0
-        stepped: set = set()
-        t0 = time.perf_counter()
         with tr.span("sim.service.step", steps=steps):
             for _ in range(steps):
                 self._admit()
@@ -228,7 +224,6 @@ class SimService:
                                  occupied=len(occ)):
                         group.ensemble.step(1)
                     if reg.enabled:
-                        stepped.add(key)
                         updates += len(occ) * group.ensemble.n_fluid_nodes
                     for slot in occ:
                         sess = group.active[slot]
@@ -242,20 +237,11 @@ class SimService:
                 if not any_active and not self.queue:
                     break
         if reg.enabled:
-            # sync before reading the clock: the dispatches above are
-            # async, so the window MFLUPS must wait for the device work.
-            # Disabled-path dispatch behaviour is untouched.
-            for key in stepped:
-                jax.block_until_ready(self.groups[key].ensemble.f)
-            wall = time.perf_counter() - t0
             for key, group in self.groups.items():
                 reg.gauge("sim.slot.occupancy", group=key[0][:8]).set(
                     len(group.occupied) / max(1, len(group.active)))
             if updates:
                 reg.counter("sim.node_updates_total").inc(updates)
-                if wall > 0:
-                    reg.gauge("sim.service.window_mflups").set(
-                        updates / wall / 1e6)
         return progressed or bool(self.queue)
 
     def run(self, max_steps: int | None = None,

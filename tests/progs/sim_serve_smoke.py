@@ -33,7 +33,7 @@ from repro.sim.service import SimService  # noqa: E402
 
 
 def main():
-    obs.enable(trace=True, device_annotations=False)
+    obs.enable(trace=True)
     box = np.ones((8, 8, 8), np.uint8)           # periodic all-fluid box
     channel = np.ones((8, 8, 8), np.uint8)       # walled forced channel
     channel[:, 0, :] = 0

@@ -6,12 +6,14 @@ mode invariants the instrumentation relies on:
 * exports are deterministic (snapshot/JSONL byte-stable without
   intervening mutations),
 * the span recorder nests correctly — including the serving chain
-  ``sim.service.step > sim.group.step > lbm.ensemble.step`` — and its
-  Chrome-trace JSON round-trips with nesting intact,
-* a DISABLED recorder is a true no-op: the jitted step graph (jaxpr) is
-  byte-identical with observability off and on, so production runs pay
-  nothing for the instrumentation hooks.
+  ``sim.service.step > sim.group.step > lbm.ensemble.step`` and the
+  engines' ``lbm.setup`` tree — and its Chrome-trace JSON round-trips with
+  nesting intact,
+* every span reaches a running profiler, recorder enabled or not,
+* the step's named scopes are always in the lowered program, and the
+  collectors' switches change nothing in it.
 """
+import hashlib
 import json
 
 import jax
@@ -209,17 +211,38 @@ def test_globals_start_disabled_and_use_restores():
     assert reg.value("c") == 1
 
 
-def test_enable_disable_flip_device_annotations():
+def test_enable_disable_flip_only_the_collectors():
+    eng = _tiny_duct("fused")
+    program = _program_digest(eng)
     try:
         obs.enable(trace=True)
         assert obs.get_metrics().enabled and obs.get_tracer().enabled
-        assert obs.device_annotations_enabled()
-        obs.enable(trace=True, device_annotations=False)
-        assert not obs.device_annotations_enabled()
+        obs.enable(metrics=False, trace=False)
+        assert not obs.get_metrics().enabled
+        assert not obs.get_tracer().enabled
+        obs.enable(metrics=True, trace=True)
+        assert _program_digest(eng) == program
     finally:
         obs.disable()
-    assert not obs.get_metrics().enabled
-    assert not obs.device_annotations_enabled()
+    assert not obs.get_metrics().enabled and not obs.get_tracer().enabled
+    assert not hasattr(obs, "set_device_annotations")
+
+
+def test_span_reaches_the_profiler_when_disabled(tmp_path):
+    from jax.profiler import ProfileData
+
+    rec = SpanRecorder(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("lbm.probe.span", steps=1):
+            jax.block_until_ready(jax.numpy.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert "lbm.probe.span" in names
+    assert rec.spans == []
 
 
 # --------------------------------------------------------------------------
@@ -235,20 +258,85 @@ def _tiny_engine(split_stream=False, backend="gather"):
     return SparseTiledLBM(geom, cfg)
 
 
-def test_disabled_mode_identical_jaxpr():
-    """The instrumentation hooks (phase_scope in the traced step body) must
-    not change the compiled program when obs is off — and jax.named_scope
-    only attaches metadata, so even fully enabled the jaxpr is identical."""
-    eng = _tiny_engine(split_stream=True)
+def _tiny_duct(backend):
+    """A walled 8x8x16 duct with a velocity inlet and a pressure outlet,
+    so the fused step runs its NEBB pass."""
+    from repro.core.boundary import BoundarySpec
+    from repro.core.engine import LBMConfig, SparseTiledLBM
+    from repro.core.tiling import INLET, OUTLET
+    from repro.data.geometry import duct
+
+    bcs = ((INLET, BoundarySpec("velocity", (0, 0, 1),
+                                velocity=(0.0, 0.0, 0.02))),
+           (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
+    cfg = LBMConfig(backend=backend, boundaries=bcs,
+                    layout_scheme="xyz" if backend == "fused" else "paper")
+    return SparseTiledLBM(duct(8, 8, 16), cfg)
+
+
+def _lowered_step(eng, debug_info: bool = True) -> str:
+    """The jitted step's StableHLO; with ``debug_info`` each op's location,
+    which carries its named scopes (and the caller's source lines)."""
+    return jax.jit(eng.backend.step).lower(
+        eng.f, eng.backend.tables).as_text(debug_info=debug_info)
+
+
+def _program_digest(eng) -> str:
+    return hashlib.sha256(
+        _lowered_step(eng, debug_info=False).encode()).hexdigest()
+
+
+def test_fused_step_lowers_with_phase_scopes():
+    """With obs disabled the fused step still names its phases: the kernel,
+    the NEBB pass and the buffer work around the kernel."""
     obs.disable()
-    off = str(jax.make_jaxpr(eng.backend.step)(eng.f, eng.backend.tables))
-    try:
-        obs.enable(metrics=True, trace=True)          # device annotations on
-        on = str(jax.make_jaxpr(eng.backend.step)(eng.f,
-                                                  eng.backend.tables))
-    finally:
-        obs.disable()
-    assert on == off
+    text = _lowered_step(_tiny_duct("fused"))
+    for scope in ("lbm.phase.stream_collide", "lbm.phase.boundary",
+                  "lbm.phase.pack"):
+        assert scope in text, scope
+
+
+SETUP_CHILDREN = ["lbm.setup.tiling", "lbm.setup.stream_tables",
+                  "lbm.setup.backend_tables", "lbm.setup.place",
+                  "lbm.setup.initial_state"]
+
+
+@pytest.mark.parametrize("backend", ["gather", "fused"])
+def test_setup_spans_nest_under_lbm_setup(backend):
+    rec = SpanRecorder()
+    with obs.use(trace=rec):
+        _tiny_duct(backend)
+    root, = rec.find("lbm.setup")
+    assert root.parent == -1 and root.attrs == {"backend": backend}
+    children = sorted((s for s in rec.spans if s.parent == root.sid),
+                      key=lambda s: s.ts_ns)
+    assert [s.name for s in children] == SETUP_CHILDREN
+    assert sum(s.dur_ns for s in children) <= root.dur_ns
+    assert len(rec.spans) == 1 + len(SETUP_CHILDREN)
+
+
+def test_disabled_recorder_records_no_setup_span():
+    rec = SpanRecorder(enabled=False)
+    with obs.use(trace=rec):
+        _tiny_duct("fused")
+    assert rec.spans == []
+
+
+def test_sharded_setup_spans_share_the_engine_names():
+    from repro.core.engine import LBMConfig
+    from repro.dist.lbm import ShardedLBM
+
+    mesh = jax.make_mesh((1,), ("data",))
+    cfg = LBMConfig(layout_scheme="xyz", periodic=(True, True, True),
+                    backend="fused")
+    rec = SpanRecorder()
+    with obs.use(trace=rec):
+        ShardedLBM(np.ones((8, 8, 8), np.uint8), cfg, mesh)
+    root, = rec.find("lbm.setup")
+    assert root.attrs == {"backend": "fused", "sharded": True}
+    children = sorted((s for s in rec.spans if s.parent == root.sid),
+                      key=lambda s: s.ts_ns)
+    assert [s.name for s in children] == SETUP_CHILDREN
 
 
 def test_engine_counters_only_when_enabled():

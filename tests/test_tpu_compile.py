@@ -5,6 +5,8 @@ geometry (its tables are arguments, not embedded constants).
 
 Only this file describes the topology, inside a fixture, so the test
 workers that never run it never load the TPU compiler library."""
+import re
+
 import pytest
 
 import jax
@@ -106,6 +108,23 @@ def test_fused_ensemble_step_compiles(one_chip, spheres):
     assert eng.tiling.num_tiles == SPHERES_TILES
     compiled = _compile_step(eng, one_chip, ensemble=2)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_run_names_its_kernel_and_boundary_pass(one_chip, spheres):
+    """The compiled ``run()`` holds the Pallas kernel under its own name
+    and no other Pallas kernel, and the NEBB pass's gather carries its
+    named scope, so a device trace can tell the two apart."""
+    eng = _engine(spheres, backend="fused")
+    text = eng.run_fn(10).lower(
+        _shapes(eng.f, one_chip),
+        _shapes(eng.backend.tables, one_chip)).compile().as_text()
+    kernels = [re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all(re.fullmatch(r"stream_collide(\.\d+)?", k)
+                           for k in kernels), kernels
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert gathers and all('lbm.phase.boundary' in g for g in gathers)
 
 
 @pytest.mark.parametrize("split", [False, True], ids=["mono", "split"])
