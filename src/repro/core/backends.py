@@ -16,9 +16,9 @@ then grows with the geometry (gigabytes at chip-filling sizes).
   PERSISTENTLY in the kernel's packed (T+1, Q, n) layout.  Packing happens
   once at init and unpacking only in diagnostics, so ``step``/``run``
   contain zero layout shuffles: the jitted hot loop is the pallas_call, a
-  scratch-row reset, and (only when open boundaries exist) one small
-  gather+scatter restricted to the boundary tiles for the NEBB
-  reconstruction pass.
+  scratch-row reset, and (only when open boundaries exist) the NEBB
+  reconstruction pass over the boundary tiles: the same kernel's pull over
+  that tile list, the rebuild and collision, and one small scatter.
 
 Both backends produce identical physics: float64 parity is pinned to 1e-12
 in tests/test_backend_fused.py on all benchmark geometry families.
@@ -85,31 +85,27 @@ def place(host):
     return tree
 
 
-def boundary_pass_tables(node_types: np.ndarray, gather_idx: np.ndarray,
-                         boundaries, q: int, n: int):
+def boundary_pass_tables(node_types: np.ndarray, neighbors: np.ndarray,
+                         boundaries):
     """Host-side tables for the fused backends' masked NEBB pass.
 
-    ``node_types``: (T, n) uint8; ``gather_idx``: (Q, T, n) streaming
-    indices in the canonical per-direction flat space.  Returns numpy
-    ``(tiles (B,), packed_gather (Q, B, n), type_masks (S, B, n),
-    solid (B, n))`` restricted to the tiles that hold boundary nodes —
-    or ``None`` when no node matches any declared boundary type (a
+    ``node_types``: (T, n) uint8; ``neighbors``: the kernel's (T, 27)
+    neighbour table.  Returns numpy ``(tiles (B,), rows (B, 27),
+    type_masks (S, B, n), solid (B, n))`` restricted to the tiles that
+    hold boundary nodes, ``rows`` being their neighbour-table rows — or
+    ``None`` when no node matches any declared boundary type (a
     declared-but-absent boundary must skip the pass, not scatter over an
-    empty (Q, 0, n) table).  Shared by ``FusedBackend`` and ``ShardedLBM``
+    empty (0, Q, n) block).  Shared by ``FusedBackend`` and ``ShardedLBM``
     so the two fused paths cannot drift.
     """
-    from repro.kernels.stream_collide import packed_gather_indices
-
-    t = node_types.shape[0]
     node_bc = np.zeros_like(node_types, bool)
     for tv, _ in boundaries:
         node_bc |= node_types == tv
     bt = np.nonzero(node_bc.any(axis=1))[0].astype(np.int32)
     if not len(bt):
         return None
-    packed = packed_gather_indices(gather_idx[:, bt, :], q, t, n)
     type_masks = np.stack([node_types[bt] == tv for tv, _ in boundaries])
-    return bt, packed, type_masks, node_types[bt] == SOLID
+    return bt, neighbors[bt], type_masks, node_types[bt] == SOLID
 
 
 def apply_split_stream(f_store, solid, *, intra, is_cross, nbr, case,
@@ -159,24 +155,28 @@ def apply_split_stream(f_store, solid, *, intra, is_cross, nbr, case,
     return jnp.where(solid[None], 0.0, f_in)
 
 
-def nebb_boundary_pass(f_pre, out, lat, collision_cfg, force, specs,
-                       tiles, gather, type_masks, solid):
+def nebb_boundary_pass(f_pre, out, types, cfg, lat, interpret, tiles, rows,
+                       type_masks, solid):
     """The fused backends' post-kernel masked NEBB pass (device-side).
 
-    Re-streams ONLY the boundary tiles from the pre-step packed state
-    ``f_pre`` via the precomputed packed-layout ``gather``, applies the
-    NEBB rebuild per boundary spec + collision + solid masking, and
-    scatters the result over the kernel output ``out``.  Exactness: the
-    rebuild sees post-streaming / pre-collision values, same as the gather
-    backend's in-line application.
+    Re-streams ONLY the boundary ``tiles`` from the pre-step packed state
+    ``f_pre`` with the fused kernel's own pull over that tile list
+    (:func:`repro.kernels.stream_collide.nebb_stream_tiles`; ``rows`` are
+    their neighbour-table rows, ``types`` the kernel's type table), applies
+    the NEBB rebuild per boundary spec of ``cfg`` + collision + solid
+    masking, and scatters the result over the kernel output ``out``.
+    Exactness: the rebuild sees post-streaming / pre-collision values, same
+    as the gather backend's in-line application.
     """
-    q, n = out.shape[-2], out.shape[-1]
+    from repro.kernels.stream_collide import nebb_stream_tiles
+
     with phase_scope("lbm.phase.boundary"):
-        f_in = jnp.take(f_pre.reshape(-1), gather.reshape(-1),
-                        axis=0).reshape(q, -1, n)           # (Q, B, n)
-        for mask, spec in zip(type_masks, specs):
+        f_in = jnp.moveaxis(nebb_stream_tiles(
+            f_pre, types, tiles, rows, lat, a=cfg.a, interpret=interpret,
+            node_order=cfg.node_order), 0, 1)               # (Q, B, n)
+        for mask, (_, spec) in zip(type_masks, cfg.boundaries):
             f_in = apply_open_boundary(f_in, mask, spec, lat)
-        f_out, _, _ = col.collide(f_in, lat, collision_cfg, force)
+        f_out, _, _ = col.collide(f_in, lat, cfg.collision, cfg.force)
         f_out = jnp.where(solid[None], 0.0, f_out)
         return out.at[tiles].set(jnp.moveaxis(f_out, 0, 1))
 
@@ -327,15 +327,16 @@ class FusedBackend:
     boundaries are handled by a post-kernel masked pass: the NEBB
     reconstruction (which must see post-streaming, pre-collision values)
     re-streams ONLY the tiles containing boundary nodes from the pre-step
-    state via a precomputed packed-layout gather, applies the boundary
-    rebuild + collision there, and scatters those tiles over the kernel
-    output.
+    state with the kernel's own pull over that tile list, applies the
+    boundary rebuild + collision there, and scatters those tiles over the
+    kernel output.  Whether the pass runs depends only on whether any tile
+    holds a declared boundary type: periodic and closed geometries skip it.
 
     Every device op of :meth:`step` sits under one named scope: the
     kernel (``%stream_collide`` in a device trace) under
     ``lbm.phase.stream_collide``, its output buffer and the scratch-row
-    reset under ``lbm.phase.pack``, the NEBB pass under
-    ``lbm.phase.boundary``.
+    reset under ``lbm.phase.pack``, the NEBB pass (its pull is
+    ``%nebb_stream``) under ``lbm.phase.boundary``.
     """
 
     name = "fused"
@@ -351,18 +352,21 @@ class FusedBackend:
                 f"layout_scheme must be 'xyz' (got {cfg.layout_scheme!r})")
         self.cfg, self.lat, self.tiling = cfg, lat, tiling
         self.interpret = interpret
-        self._bc_specs = tuple(spec for _, spec in cfg.boundaries)
         with obs.get_tracer().span("lbm.setup.backend_tables"):
             self._types_np = kernel_node_types(tiling.node_types)  # (T+1,1,n)
             self._nbrs_np = build_neighbor_table(tiling, cfg.periodic)
             self._bc_np = (boundary_pass_tables(
-                tiling.node_types, tables.gather_idx, cfg.boundaries, lat.q,
-                tiling.nodes_per_tile)
+                tiling.node_types, self._nbrs_np, cfg.boundaries)
                 if cfg.boundaries and cfg.kernel_mode == "full" else None)
             host = self._host_tables(1)
         self._solid, self.tables = place(
             (tiling.node_types == SOLID, host))
         self._ens_tables: dict[int, dict] = {1: self.tables}
+        reg = obs.get_metrics()
+        if reg.enabled and self._bc_np is not None:
+            b = len(self._bc_np[0])
+            reg.gauge("lbm.nebb.tiles").set(b)
+            reg.gauge("lbm.nebb.tile_share").set(b / tiling.num_tiles)
 
     # ------------------------------------------------------------ state
     def initial_state(self, feq_canon: jnp.ndarray) -> jnp.ndarray:
@@ -391,8 +395,8 @@ class FusedBackend:
         if "bc" in tab:
             bc = tab["bc"]
             out = nebb_boundary_pass(
-                f, out, self.lat, cfg.collision, cfg.force, self._bc_specs,
-                bc["tiles"], bc["gather"], bc["type_masks"], bc["solid"])
+                f, out, tab["types"], cfg, self.lat, self.interpret,
+                bc["tiles"], bc["nbrs"], bc["type_masks"], bc["solid"])
         return out
 
     # the step is shape-generic: B is carried by the tables and the state
@@ -406,9 +410,9 @@ class FusedBackend:
         Replica b's tiles occupy rows [b*T, (b+1)*T); the single scratch
         row moves to index B*T.  The neighbour table gets the per-replica
         row offset folded in (scratch references remapped to B*T), and the
-        NEBB boundary tables get the matching packed-flat offset
-        ``b * T * Q * n``, so :func:`nebb_boundary_pass` runs unmodified
-        over all replicas' boundary tiles in one pass.
+        NEBB boundary tiles and their neighbour rows get the same offsets,
+        so :func:`nebb_boundary_pass` runs unmodified over all replicas'
+        boundary tiles in one pass.
         """
         if batch not in self._ens_tables:
             with obs.get_tracer().span("lbm.setup.backend_tables"):
@@ -418,21 +422,24 @@ class FusedBackend:
 
     def _host_tables(self, batch: int) -> dict:
         """The numpy tables :meth:`ensemble_tables` places."""
-        t, n = self.tiling.num_tiles, self.tiling.nodes_per_tile
-        q = self.lat.q
-        nbrs = np.concatenate(
-            [np.where(self._nbrs_np == t, batch * t, self._nbrs_np + b * t)
-             for b in range(batch)]).astype(np.int32)
+        t = self.tiling.num_tiles
+
+        def replicate(nb):
+            """Neighbour rows of every replica: row offset b*T, scratch
+            references remapped to B*T."""
+            return np.concatenate(
+                [np.where(nb == t, batch * t, nb + b * t)
+                 for b in range(batch)]).astype(np.int32)
+
         types = np.concatenate([self._types_np[:t]] * batch
                                + [self._types_np[t:]])
-        tab = {"types": types, "nbrs": nbrs}
+        tab = {"types": types, "nbrs": replicate(self._nbrs_np)}
         if self._bc_np is not None:
-            bt, packed, type_masks, solid_b = self._bc_np
+            bt, rows, type_masks, solid_b = self._bc_np
             tab["bc"] = {
                 "tiles": np.concatenate(
                     [bt + b * t for b in range(batch)]).astype(np.int32),
-                "gather": np.concatenate(
-                    [packed + b * t * q * n for b in range(batch)], axis=1),
+                "nbrs": replicate(rows),
                 "type_masks": np.concatenate([type_masks] * batch, axis=1),
                 "solid": np.concatenate([solid_b] * batch),
             }

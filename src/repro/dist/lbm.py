@@ -328,7 +328,7 @@ class ShardedLBM:
                  "own_nodes": P("slab", None, None)}
 
         if self.fused:
-            self._build_fused_tables(tbl, specs, types, tabs_of_dev, periodic)
+            self._build_fused_tables(tbl, specs, types, periodic)
         else:
             if cfg.split_stream:
                 self._build_split_tables(tbl, specs, tabs_of_dev)
@@ -437,15 +437,15 @@ class ShardedLBM:
         specs.update(sp_nbr=P("slab", None, None), sp_bdst=P("slab", None),
                      sp_idst=P("slab", None), sp_isrc=P("slab", None))
 
-    def _build_fused_tables(self, tbl, specs, types, tabs_of_dev,
-                            periodic) -> None:
+    def _build_fused_tables(self, tbl, specs, types, periodic) -> None:
         """Per-slab tables for the fused kernel: neighbour tables (dummy
-        slot = scratch tile) and the packed-layout boundary-pass tables."""
+        slot = scratch tile) and the boundary-pass tables (boundary tiles
+        and their neighbour rows)."""
         from repro.core.backends import boundary_pass_tables
         from repro.kernels.stream_collide import build_neighbor_table
 
         cfg, plan = self.cfg, self.plan
-        q, tp, n = self.lat.q, plan.t_pad, plan.nodes_per_tile
+        n = plan.nodes_per_tile
         d_cnt, dummy = plan.n_dev, plan.t_pad - 1
 
         # the kernel's (Tp, 1, n) int32 type table per slab; padding tiles
@@ -462,34 +462,31 @@ class ShardedLBM:
         if not (cfg.boundaries and cfg.kernel_mode == "full"):
             return
         # per-device boundary-pass tables from the shared builder, padded to
-        # a common width; padded rows target the dummy tile's (zero) slots.
+        # a common width; padded rows are the dummy tile, whose neighbour
+        # row is all dummy and whose (zero) slots the step resets.
         # A device (or the whole fleet) may have NO boundary nodes — the
         # builder returns None there and the pass is skipped entirely when
         # no device needs it.
-        per_dev = [boundary_pass_tables(lt.node_types,
-                                        tabs_of_dev[d].gather_idx,
-                                        cfg.boundaries, q, n)
+        per_dev = [boundary_pass_tables(lt.node_types, nbrs[d],
+                                        cfg.boundaries)
                    for d, lt in enumerate(plan.local_tilings)]
         if all(r is None for r in per_dev):
             return
         b_max = max(len(r[0]) for r in per_dev if r is not None)
-        qi = np.arange(q)[:, None, None]
-        oi = np.arange(n)[None, None, :]
         bct = np.full((d_cnt, b_max), dummy, np.int32)
-        bcg = np.broadcast_to(dummy * (q * n) + qi * n + oi,
-                              (d_cnt, q, b_max, n)).copy().astype(np.int32)
+        bcn = np.full((d_cnt, b_max, 27), dummy, np.int32)
         bcm = np.zeros((len(cfg.boundaries), d_cnt, b_max, n), bool)
         bcs = np.ones((d_cnt, b_max, n), bool)
         for d, r in enumerate(per_dev):
             if r is None:
                 continue
-            bt, packed, type_masks, solid_b = r
+            bt, rows, type_masks, solid_b = r
             bct[d, :len(bt)] = bt
-            bcg[d, :, :len(bt)] = packed
+            bcn[d, :len(bt)] = rows
             bcm[:, d, :len(bt)] = type_masks
             bcs[d, :len(bt)] = solid_b
-        tbl.update(bct=bct, bcg=bcg, bcm=bcm, bcs=bcs)
-        specs.update(bct=P("slab", None), bcg=P("slab", None, None, None),
+        tbl.update(bct=bct, bcn=bcn, bcm=bcm, bcs=bcs)
+        specs.update(bct=P("slab", None), bcn=P("slab", None, None),
                      bcm=P(None, "slab", None, None),
                      bcs=P("slab", None, None))
 
@@ -613,13 +610,12 @@ class ShardedLBM:
                 f, tbl["types"][0], tbl["nbrs"][0], lat, cfg.collision,
                 a=cfg.a, force=cfg.force, interpret=self.kernel_interpret,
                 mode=cfg.kernel_mode, node_order=cfg.node_order)
-            if "bcg" in tbl:
+            if "bcn" in tbl:
                 # masked NEBB pass (shared with FusedBackend): re-stream +
                 # rebuild + collide ONLY the boundary tiles, pre-step state
                 out = nebb_boundary_pass(
-                    f, out, lat, cfg.collision, cfg.force,
-                    tuple(spec for _, spec in cfg.boundaries),
-                    tbl["bct"][0], tbl["bcg"][0], tbl["bcm"][:, 0],
+                    f, out, tbl["types"][0], cfg, lat, self.kernel_interpret,
+                    tbl["bct"][0], tbl["bcn"][0], tbl["bcm"][:, 0],
                     tbl["bcs"][0])
                 with obs.phase_scope("lbm.phase.pack"):
                     # padded rows hit the dummy

@@ -135,23 +135,6 @@ def build_neighbor_table(
     return np.where(nbr < 0, t, nbr).astype(np.int32)
 
 
-def packed_gather_indices(gather_idx: np.ndarray, q: int, t: int,
-                          n: int) -> np.ndarray:
-    """Remap streaming gather indices into the packed (T+1, Q, n) flat space.
-
-    ``gather_idx`` comes from :func:`repro.core.streaming.build_stream_tables`
-    and indexes the canonical per-direction flat layout
-    ``idx = q * (t*n) + tile * n + off``; the packed layout used by the fused
-    kernel flattens as ``idx = tile * (q*n) + q * n + off``.  Only valid for
-    ``layout_scheme='xyz'`` (identity within-tile permutations).
-    """
-    g = gather_idx.astype(np.int64)
-    m = t * n
-    qq, rem = np.divmod(g, m)
-    tile, off = np.divmod(rem, n)
-    return (tile * (q * n) + qq * n + off).astype(np.int32)
-
-
 def make_kernel(lat: Lattice, cfg: col.CollisionConfig, n_offsets: int,
                 force=None, mode: str = "full"):
     """Kernel body for one tile.
@@ -225,6 +208,36 @@ def make_kernel(lat: Lattice, cfg: col.CollisionConfig, n_offsets: int,
     return kernel
 
 
+def _pull_inputs(f, node_types, lat: Lattice, a: int, node_order: str,
+                 nw: int, own_map):
+    """BlockSpecs and operands of the pull, shared by the kernel's two
+    calls (:func:`stream_collide_tiles`, :func:`nebb_stream_tiles`): the
+    own tile's f and types blocks (``own_map``), the static perms/cases
+    tables, then f and types of each linked neighbour tile, whose id the
+    index map reads from the neighbour rows prefetched as the LAST
+    scalar-prefetch operand (``nw`` entries per grid step)."""
+    q, n = f.shape[1], f.shape[2]
+    offsets, perms, cases = _pull_geometry(lat, a, node_order)
+    table_spec = pl.BlockSpec((q, n), lambda i, *_: (0, 0))
+    in_specs = [
+        pl.BlockSpec((1, q, n), own_map),                    # own f
+        pl.BlockSpec((1, 1, n), own_map),                    # own types
+        table_spec, table_spec,                              # perms, cases
+    ]
+    operands = [f, node_types, jnp.asarray(perms),
+                jnp.asarray(cases, jnp.int32)]
+    for off in offsets:
+        k = neighbor_offset_index(*off)
+
+        def nb_map(i, *prefetched, _k=k):
+            return (prefetched[-1][i * nw + _k], 0, 0)
+
+        in_specs.append(pl.BlockSpec((1, q, n), nb_map))
+        in_specs.append(pl.BlockSpec((1, 1, n), nb_map))
+        operands.extend([f, node_types])
+    return in_specs, operands
+
+
 def _rw_kernel(own_f, out_ref):
     """paper §4.1 'rw_only' variant: read + write the tile's own block."""
     out_ref[0] = own_f[0]
@@ -280,34 +293,17 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
         with phase_scope("lbm.phase.pack"):
             return zero_scratch_row(out, t)
 
-    offsets, perms_np, cases_np = _pull_geometry(lat, a, node_order)
+    offsets = _pull_geometry(lat, a, node_order)[0]
     kernel = make_kernel(lat, cfg, len(offsets), force, mode)
 
     assert node_types.shape == (t1, 1, n), node_types.shape
     nw = neighbors.shape[1]
-    perms = jnp.asarray(perms_np)
-    cases = jnp.asarray(cases_np, jnp.int32)
 
     def own_map(i, start, nb):
         return (start[0] + i, 0, 0)
 
-    table_spec = pl.BlockSpec((q, n), lambda i, start, nb: (0, 0))
-    in_specs = [
-        pl.BlockSpec((1, q, n), own_map),                    # own f
-        pl.BlockSpec((1, 1, n), own_map),                    # own types
-        table_spec, table_spec,                              # perms, cases
-    ]
-    operands = [f, node_types, perms, cases]
-    for off in offsets:
-        k = neighbor_offset_index(*off)
-
-        def nb_map(i, start, nb, _k=k):
-            return (nb[i * nw + _k], 0, 0)
-
-        in_specs.append(pl.BlockSpec((1, q, n), nb_map))
-        in_specs.append(pl.BlockSpec((1, 1, n), nb_map))
-        operands.extend([f, node_types])
-
+    in_specs, operands = _pull_inputs(f, node_types, lat, a, node_order, nw,
+                                      own_map)
     if cfg.model == col.LBMRT and mode == "full":
         in_specs.append(pl.BlockSpec((q, q), lambda i, start, nb: (0, 0)))
         operands.append(jnp.asarray(col.collision_matrix_np(lat, cfg.tau),
@@ -350,6 +346,71 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
         out = jax.lax.fori_loop(0, n_chunks, run_chunk, out)
     with phase_scope("lbm.phase.pack"):
         return zero_scratch_row(out, t)
+
+
+def nebb_stream_tiles(f, node_types, tiles, rows, lat: Lattice, a: int = 4,
+                      interpret: bool | None = None,
+                      node_order: str = "canonical"):
+    """The fused kernel's pull over a LIST of tiles: streaming plus
+    half-way bounce-back, no collision (``mode='propagation_only'``).
+
+    f, node_types: as in :func:`stream_collide_tiles` (scratch row last)
+    tiles:      (B,) int32 tile rows of ``f`` to pull
+    rows:       (B, 27) int32 their neighbour-table rows (``nbrs[tiles]``)
+    Returns the post-streaming, pre-collision (B, Q, n) block.
+
+    One Pallas call named ``nebb_stream`` per chunk of ``TILES_PER_CALL``
+    list entries (``%nebb_stream.N`` in a device trace): the own-tile
+    index map reads the tile id from the prefetched list, the neighbour
+    index maps read the prefetched rows, and the kernel body is
+    :func:`make_kernel`'s, so every value equals what the main call pulls
+    for that tile.  The last chunk is clamped to end at entry B-1, as in
+    the main call.
+    """
+    from .ops import resolve_interpret
+
+    interpret = resolve_interpret(interpret)
+    q, n = f.shape[1], f.shape[2]
+    b, nw = rows.shape
+    offsets = _pull_geometry(lat, a, node_order)[0]
+    pull = make_kernel(lat, col.CollisionConfig(), len(offsets),
+                       mode="propagation_only")
+
+    def kernel(start_ref, ids_ref, nb_ref, *refs):
+        pull(start_ref, nb_ref, *refs)
+
+    def own_map(i, start, ids, nb):
+        return (ids[i], 0, 0)
+
+    in_specs, operands = _pull_inputs(f, node_types, lat, a, node_order, nw,
+                                      own_map)
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # aliased output
+    chunk = min(b, TILES_PER_CALL)
+    n_chunks = -(-b // chunk)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(chunk,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, q, n), lambda i, start, ids, nb: (start[0] + i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, q, n), f.dtype),
+        input_output_aliases={3 + len(operands): 0},
+        interpret=interpret,
+        name="nebb_stream",
+    )
+    rows_flat = rows.reshape(-1)
+
+    def run_chunk(c, out):
+        start = jnp.minimum(c * chunk, b - chunk)
+        ids = jax.lax.dynamic_slice_in_dim(tiles, start, chunk)
+        nb = jax.lax.dynamic_slice_in_dim(rows_flat, start * nw, chunk * nw)
+        return call(start[None], ids, nb, *operands, out)
+
+    return jax.lax.fori_loop(0, n_chunks, run_chunk,
+                             jnp.zeros((b, q, n), f.dtype))
 
 
 def kernel_node_types(node_types: np.ndarray) -> np.ndarray:

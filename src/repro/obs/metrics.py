@@ -59,6 +59,9 @@ CATALOGUE = {
     "lbm.stream.frontier_frac": "gauge: fraction of links crossing tiles",
     "lbm.stream.bounce_frac": "gauge: fraction of links that bounce",
     "lbm.tiles.utilisation": "gauge: fluid nodes / stored nodes (eta_t)",
+    "lbm.nebb.tiles": "gauge: boundary tiles the fused NEBB pass "
+                      "re-streams per step",
+    "lbm.nebb.tile_share": "gauge: lbm.nebb.tiles / all tiles",
     # ---- serving layer ------------------------------------------------
     "sim.session.submitted_total": "counter: sessions submitted",
     "sim.session.admitted_total": "counter: sessions seated into slots",

@@ -175,9 +175,11 @@ def test_primitive_walker_sees_gather_backend_shuffles():
 
 
 def test_fused_boundary_pass_only_adds_tile_local_work():
-    """With open boundaries the fused loop may gather/scatter, but only on
-    the boundary-tile subset — the full-state (T, Q, n) array must never be
-    transposed (that would be a pack/unpack round-trip)."""
+    """With open boundaries the fused loop adds work on the boundary-tile
+    subset only: the full-state (T, Q, n) array is never transposed (that
+    would be a pack/unpack round-trip), and nothing is gathered outside
+    the Pallas calls — the boundary tiles are re-streamed by the kernel's
+    own pull (a second pallas_call), then scattered back."""
     g = duct_wrap(_spheres(), wall=4)
     eng = SparseTiledLBM(
         g, LBMConfig(backend="fused", dtype="float64", boundaries=BCS,
@@ -194,6 +196,7 @@ def test_fused_boundary_pass_only_adds_tile_local_work():
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
                 continue
+            assert eqn.primitive.name != "gather", eqn
             if eqn.primitive.name == "transpose":
                 # only the small (Q, B, n) boundary block may be transposed
                 assert eqn.invars[0].aval.size <= eng.lat.q * b * (
@@ -204,3 +207,4 @@ def test_fused_boundary_pass_only_adds_tile_local_work():
                     _check(sub)
 
     _check(closed.jaxpr)
+    assert _collect_primitives(closed.jaxpr, []).count("pallas_call") == 2

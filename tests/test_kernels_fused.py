@@ -6,9 +6,16 @@ import numpy as np
 import pytest
 
 from repro.core import collision as C
+from repro.core.backends import boundary_pass_tables
+from repro.core.boundary import BoundarySpec
 from repro.core.engine import LBMConfig, SparseTiledLBM
 from repro.core.lattice import d3q19
+from repro.core.streaming import build_stream_tables
+from repro.core.tiling import INLET, OUTLET, tile_geometry
+from repro.data.geometry import duct_wrap, random_spheres
+from repro.kernels import stream_collide as sc
 from repro.kernels.stream_collide import (
+    build_neighbor_table, kernel_node_types, nebb_stream_tiles,
     pack_engine_state, stream_collide_tiles, unpack_engine_state,
 )
 
@@ -64,3 +71,43 @@ def test_fused_kernel_multi_step_and_mass():
     assert err < 2e-4, err
     # closed box (bounce-back everywhere): mass conserved through the kernel
     assert abs(float(jnp.sum(fp)) - m0) / m0 < 1e-4  # f32 sum noise
+
+
+BCS = ((INLET, BoundarySpec("velocity", (0, 0, 1), velocity=(0, 0, 0.03))),
+       (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
+
+
+@pytest.mark.usefixtures("x64")
+@pytest.mark.parametrize("node_order,tile_order,chunk", [
+    ("canonical", "zmajor", None),
+    ("sfc", "zmajor", None),
+    ("canonical", "hilbert", None),
+    ("frontier_last", "morton", 7),
+])
+def test_nebb_stream_equals_the_stream_tables_pull(node_order, tile_order,
+                                                   chunk, monkeypatch):
+    """The tile-list pull (``nebb_stream``) over the boundary tiles of the
+    duct-wrapped sphere pack returns, bitwise at every node of every listed
+    tile, the pull that the streaming gather table defines.  That table is
+    the oracle here only; ``chunk`` shrinks ``TILES_PER_CALL`` so that the
+    list runs in several calls with a clamped last one."""
+    if chunk:
+        monkeypatch.setattr(sc, "TILES_PER_CALL", chunk)
+    lat = d3q19()
+    g = duct_wrap(random_spheres(box=16, porosity=0.6, diameter=8, seed=1),
+                  wall=4)
+    tiling = tile_geometry(g, 4, order=tile_order, node_order=node_order)
+    t, n = tiling.num_tiles, tiling.nodes_per_tile
+    gather = build_stream_tables(tiling, lat, "xyz").gather_idx   # (Q, T, n)
+    bt, rows, _, _ = boundary_pass_tables(
+        tiling.node_types, build_neighbor_table(tiling), BCS)
+    assert 0 < len(bt) < t and (not chunk or len(bt) % chunk)
+    f = np.random.default_rng(7).random((lat.q, t, n))
+    packed = np.zeros((t + 1, lat.q, n))
+    packed[:t] = f.transpose(1, 0, 2)
+    blk = nebb_stream_tiles(
+        jnp.asarray(packed), jnp.asarray(kernel_node_types(tiling.node_types)),
+        jnp.asarray(bt), jnp.asarray(rows), lat, node_order=node_order,
+        interpret=True)
+    want = f.reshape(-1)[gather[:, bt, :]].transpose(1, 0, 2)     # (B, Q, n)
+    np.testing.assert_array_equal(np.asarray(blk), want)

@@ -352,6 +352,40 @@ def test_engine_counters_only_when_enabled():
     assert reg.value("lbm.step_total") == 5
 
 
+def test_nebb_gauges_count_the_boundary_tiles():
+    """The fused backend reports, at construction, the tiles its NEBB pass
+    re-streams each step and their share of all tiles; a disabled
+    registry gets nothing."""
+    from repro.core.tiling import INLET, OUTLET
+
+    reg = MetricRegistry()
+    with obs.use(metrics=reg):
+        eng = _tiny_duct("fused")
+    types = eng.tiling.node_types
+    b = int(np.isin(types, (INLET, OUTLET)).any(axis=1).sum())
+    assert 0 < b < eng.tiling.num_tiles
+    assert b == len(eng.backend.tables["bc"]["tiles"])
+    assert reg.value("lbm.nebb.tiles") == b
+    assert reg.value("lbm.nebb.tile_share") == b / eng.tiling.num_tiles
+    off = MetricRegistry(enabled=False)
+    with obs.use(metrics=off):
+        _tiny_duct("fused")
+    assert off.value("lbm.nebb.tiles") is None
+
+
+def test_nebb_gauges_absent_without_boundaries():
+    from repro.core.engine import LBMConfig, SparseTiledLBM
+
+    reg = MetricRegistry()
+    with obs.use(metrics=reg):
+        eng = SparseTiledLBM(np.ones((8, 8, 8), np.uint8), LBMConfig(
+            layout_scheme="xyz", periodic=(True, True, True),
+            backend="fused"))
+    assert "bc" not in eng.backend.tables
+    assert reg.value("lbm.nebb.tiles") is None
+    assert reg.value("lbm.nebb.tile_share") is None
+
+
 def test_model_metrics_names_and_sanity():
     eng = _tiny_engine(split_stream=True)
     m = eng.model_metrics()

@@ -30,6 +30,7 @@ from repro.core.tiling import (INLET, NODE_ORDERS, OUTLET, SOLID, TILE_ORDERS,
                                node_order_permutation, static_frontier_mask,
                                tile_geometry, untile)
 from repro.data.geometry import duct_wrap, random_spheres, vessel_aneurysm
+from repro.kernels.stream_collide import build_neighbor_table
 
 BCS = ((INLET, BoundarySpec("velocity", (0, 0, 1), velocity=(0, 0, 0.03))),
        (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
@@ -318,13 +319,10 @@ def test_fused_parity_under_node_orders(node_order):
 
 # ------------------------------------------- absent boundary type (fix)
 def test_boundary_pass_tables_empty_returns_none():
-    lat = get_lattice("D3Q19")
     t = tile_geometry(np.ones((8, 8, 8), np.uint8), 4)
-    tabs = build_stream_tables(t, lat, "xyz")
     # INLET declared, but the geometry holds only FLUID nodes
-    out = boundary_pass_tables(t.node_types, tabs.gather_idx,
-                               ((INLET, BCS[0][1]),), lat.q,
-                               t.nodes_per_tile)
+    out = boundary_pass_tables(t.node_types, build_neighbor_table(t),
+                               ((INLET, BCS[0][1]),))
     assert out is None
 
 

@@ -110,21 +110,35 @@ def test_fused_ensemble_step_compiles(one_chip, spheres):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_run_names_its_kernel_and_boundary_pass(one_chip, spheres):
-    """The compiled ``run()`` holds the Pallas kernel under its own name
-    and no other Pallas kernel, and the NEBB pass's gather carries its
-    named scope, so a device trace can tell the two apart."""
-    eng = _engine(spheres, backend="fused")
+def _compiled_run_kernels(eng, sharding):
+    """The compiled ``run(10)``'s text and the names of its Pallas calls."""
     text = eng.run_fn(10).lower(
-        _shapes(eng.f, one_chip),
-        _shapes(eng.backend.tables, one_chip)).compile().as_text()
+        _shapes(eng.f, sharding),
+        _shapes(eng.backend.tables, sharding)).compile().as_text()
     kernels = [re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line).group(1)
                for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert kernels and all(re.fullmatch(r"stream_collide(\.\d+)?", k)
-                           for k in kernels), kernels
-    gathers = [line for line in text.splitlines() if " gather(" in line]
-    assert gathers and all('lbm.phase.boundary' in g for g in gathers)
+    return text, {k.split(".")[0] for k in kernels}
+
+
+def test_fused_run_names_its_kernel_and_boundary_pass(one_chip, spheres):
+    """The compiled ``run()`` holds the fused kernel and the NEBB pass's
+    tile-list pull, each under its own name, no other Pallas kernel, and
+    no XLA gather: the boundary tiles are re-streamed block by block by
+    the kernel's pull, not element by element from the flattened state."""
+    text, names = _compiled_run_kernels(_engine(spheres, backend="fused"),
+                                        one_chip)
+    assert names == {"stream_collide", "nebb_stream"}, names
+    assert " gather(" not in text
+
+
+def test_fused_run_without_boundaries_names_one_kernel(one_chip, spheres):
+    """A geometry with no declared boundaries skips the NEBB pass: the
+    compiled ``run()`` holds the fused kernel alone."""
+    text, names = _compiled_run_kernels(
+        _engine(spheres, boundaries=(), backend="fused"), one_chip)
+    assert names == {"stream_collide"}, names
+    assert " gather(" not in text
 
 
 @pytest.mark.parametrize("split", [False, True], ids=["mono", "split"])
