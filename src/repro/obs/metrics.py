@@ -74,6 +74,14 @@ CATALOGUE = {
     # ---- distributed layer --------------------------------------------
     "dist.halo.bytes": "gauge: halo-exchange bytes per step (all devices)",
     "dist.halo.bytes_total": "counter: cumulative halo-exchange bytes",
+    "dist.halo.tiles": "gauge: tile rows the busiest device sends per "
+                       "step (halo exchange, padded lists)",
+    "dist.slab.count": "gauge: z slabs of the sharded domain",
+    "dist.slab.own_tiles_max": "gauge: most tiles one slab owns",
+    "dist.slab.own_tiles_min": "gauge: fewest tiles one slab owns",
+    "dist.slab.own_tiles_mean": "gauge: tiles a slab owns on average",
+    "dist.slab.t_pad": "gauge: tile rows each device holds (owned, halo, "
+                       "padding and the dummy row)",
     "dist.watchdog.step_seconds": "gauge: last step wall time observed",
     "dist.watchdog.straggler_total": "counter: watchdog straggler trips",
     # ---- checkpoint store ---------------------------------------------

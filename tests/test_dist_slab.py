@@ -99,3 +99,93 @@ def test_duct_wrap_closes_porous_block():
         w[1:-1, 1:-1, 0] == INLET, g[:, :, 0] == FLUID)
     np.testing.assert_array_equal(
         w[1:-1, 1:-1, -1] == OUTLET, g[:, :, -1] == FLUID)
+
+
+# --------------------------------------------------------------------------
+# the sharded engine's fused set-up and its per-slab state API
+# --------------------------------------------------------------------------
+def _fused_sharded(slabs: int = 4, dryrun: bool = True):
+    """A fused ``ShardedLBM`` of a duct-wrapped sphere pack (34x34x32, 8
+    tile layers) with a velocity inlet and a pressure outlet, over a mesh
+    that repeats the one CPU device ``slabs`` times (built as for a dry
+    run: nothing is placed)."""
+    import jax
+    from jax.sharding import Mesh
+
+    from repro.core import collision as C
+    from repro.core.boundary import BoundarySpec
+    from repro.core.engine import LBMConfig
+    from repro.core.tiling import INLET, OUTLET
+    from repro.dist.lbm import ShardedLBM
+
+    g = geo.duct_wrap(geo.random_spheres(box=32, porosity=0.7, diameter=8,
+                                         seed=0))
+    bcs = ((INLET, BoundarySpec("velocity", (0, 0, 1),
+                                velocity=(0, 0, 0.02))),
+           (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
+    cfg = LBMConfig(collision=C.CollisionConfig(tau=0.6), backend="fused",
+                    boundaries=bcs)
+    mesh = Mesh(np.array([jax.devices()[0]] * slabs), ("data",))
+    return g, ShardedLBM(g, cfg, mesh, dryrun=dryrun)
+
+
+# sha256 (first 16 hex digits) of each fused step table of _fused_sharded()
+# over its bytes, shape and dtype: the tables the kernel, the NEBB pass and
+# the halo exchange read, pinned bit for bit
+FUSED_TABLE_DIGESTS = {
+    "bcm": "89364d91d257ed77", "bcn": "774eca8c007ad457",
+    "bcs": "65f8d79d376ed71d", "bct": "bfc275ebfa76ecbd",
+    "nbrs": "fe496db75d025907", "own_nodes": "a691cf25005b0797",
+    "rd": "f358e613aff5a6e2", "rdm": "718965407a54beef",
+    "ru": "9a638aaa3e7eb18f", "rum": "0b8fb940f0071727",
+    "sd": "6d44d9f2e833a0cc", "solid": "e3e1cd7c06d32910",
+    "su": "84eba82789f43ac7", "types": "94b3b7b3d27bdb94",
+}
+
+
+def test_fused_sharded_builds_no_stream_tables(monkeypatch):
+    import repro.dist.lbm as dist_lbm
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the fused path built stream tables")
+
+    monkeypatch.setattr(dist_lbm, "build_stream_tables", refuse)
+    _, eng = _fused_sharded()
+    assert "gather" not in eng.table_shapes()
+    assert eng.stream_fracs is None
+    assert not any(k.startswith("lbm.stream.") for k in eng.model_metrics())
+
+
+def test_fused_sharded_tables_keep_their_bits():
+    import hashlib
+
+    _, eng = _fused_sharded()
+    got = {}
+    for k, v in eng._tbl_np.items():
+        v = np.ascontiguousarray(v)
+        got[k] = hashlib.sha256(
+            v.tobytes() + str((v.shape, v.dtype)).encode()).hexdigest()[:16]
+    assert got == FUSED_TABLE_DIGESTS
+
+
+def test_owned_node_coords_cover_the_tiling_once():
+    g, eng = _fused_sharded()
+    coords = eng.owned_node_coords()
+    assert [len(c) for c in coords] == list(eng.plan.own.sum(axis=1))
+    whole = tile_geometry(g, 4).node_coords()
+    key = lambda c: np.sort(np.ravel_multi_index(            # noqa: E731
+        tuple(c.reshape(-1, 3).T), (36, 36, 32)))
+    np.testing.assert_array_equal(key(np.concatenate(coords)), key(whole))
+
+
+def test_load_then_read_owned_round_trips():
+    _, eng = _fused_sharded(slabs=1, dryrun=False)
+    rng = np.random.default_rng(3)
+    state = [rng.random((19, len(c), 64), np.float32)
+             for c in eng.owned_node_coords()]
+    eng.load_state(state)
+    back = eng.read_owned()
+    assert len(back) == 1
+    np.testing.assert_array_equal(np.asarray(back[0]), state[0])
+    with pytest.raises(ValueError):
+        eng.load_state([state[0][:, 1:]])

@@ -323,6 +323,9 @@ def test_disabled_recorder_records_no_setup_span():
 
 
 def test_sharded_setup_spans_share_the_engine_names():
+    """The sharded engine's set-up spans are the engine's; its fused
+    backend reads no stream tables, so it builds none and has no
+    ``lbm.setup.stream_tables`` span."""
     from repro.core.engine import LBMConfig
     from repro.dist.lbm import ShardedLBM
 
@@ -336,7 +339,67 @@ def test_sharded_setup_spans_share_the_engine_names():
     assert root.attrs == {"backend": "fused", "sharded": True}
     children = sorted((s for s in rec.spans if s.parent == root.sid),
                       key=lambda s: s.ts_ns)
-    assert [s.name for s in children] == SETUP_CHILDREN
+    assert [s.name for s in children] == [
+        name for name in SETUP_CHILDREN if name != "lbm.setup.stream_tables"]
+
+
+SLAB_GAUGES = ("dist.slab.count", "dist.slab.own_tiles_max",
+               "dist.slab.own_tiles_min", "dist.slab.own_tiles_mean",
+               "dist.slab.t_pad", "dist.halo.tiles")
+
+
+def _sharded_duct(slabs: int = 4):
+    """A fused ``ShardedLBM`` of the walled duct with open ends in
+    ``slabs`` z slabs, built as for a dry run (nothing placed) over a mesh
+    that repeats the one CPU device."""
+    from jax.sharding import Mesh
+
+    from repro.core.boundary import BoundarySpec
+    from repro.core.engine import LBMConfig
+    from repro.core.tiling import INLET, OUTLET
+    from repro.data.geometry import duct
+    from repro.dist.lbm import ShardedLBM
+
+    bcs = ((INLET, BoundarySpec("velocity", (0, 0, 1),
+                                velocity=(0.0, 0.0, 0.02))),
+           (OUTLET, BoundarySpec("pressure", (0, 0, -1), rho=1.0)))
+    mesh = Mesh(np.array([jax.devices()[0]] * slabs), ("data",))
+    return ShardedLBM(duct(8, 8, 32), LBMConfig(backend="fused",
+                                                boundaries=bcs),
+                      mesh, dryrun=True)
+
+
+def test_slab_gauges_set_at_construction_when_enabled():
+    from repro.obs.metrics import CATALOGUE
+
+    assert all(name in CATALOGUE for name in SLAB_GAUGES)
+    reg = MetricRegistry()
+    with obs.use(metrics=reg):
+        eng = _sharded_duct()
+    own = eng.plan.own.sum(axis=1)
+    h = eng.table_shapes()["su"].shape[1]
+    assert reg.value("dist.slab.count") == 4
+    assert reg.value("dist.slab.own_tiles_max") == own.max()
+    assert reg.value("dist.slab.own_tiles_min") == own.min()
+    assert reg.value("dist.slab.own_tiles_mean") == own.mean()
+    assert reg.value("dist.slab.t_pad") == eng.plan.t_pad
+    # the inner slabs send one layer up and one down
+    assert reg.value("dist.halo.tiles") == 2 * h
+    off = MetricRegistry(enabled=False)
+    with obs.use(metrics=off):
+        _sharded_duct()
+    assert all(off.value(name) is None for name in SLAB_GAUGES)
+
+
+def test_sharded_run_program_unchanged_by_the_registry():
+    programs = []
+    for enabled in (False, True):
+        with obs.use(metrics=MetricRegistry(enabled=enabled)):
+            eng = _sharded_duct()
+        programs.append(str(jax.make_jaxpr(eng.run_fn(10))(
+            eng.state_shape(), eng.table_shapes())))
+    assert "ppermute" in programs[0]
+    assert programs[0] == programs[1]
 
 
 def test_engine_counters_only_when_enabled():
