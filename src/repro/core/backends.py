@@ -155,14 +155,14 @@ def apply_split_stream(f_store, solid, *, intra, is_cross, nbr, case,
     return jnp.where(solid[None], 0.0, f_in)
 
 
-def nebb_boundary_pass(f_pre, out, types, cfg, lat, interpret, tiles, rows,
+def nebb_boundary_pass(f_pre, out, mask, cfg, lat, interpret, tiles, rows,
                        type_masks, solid):
     """The fused backends' post-kernel masked NEBB pass (device-side).
 
     Re-streams ONLY the boundary ``tiles`` from the pre-step packed state
     ``f_pre`` with the fused kernel's own pull over that tile list
     (:func:`repro.kernels.stream_collide.nebb_stream_tiles`; ``rows`` are
-    their neighbour-table rows, ``types`` the kernel's type table), applies
+    their neighbour-table rows, ``mask`` the kernel's bounce mask), applies
     the NEBB rebuild per boundary spec of ``cfg`` + collision + solid
     masking, and scatters the result over the kernel output ``out``.
     Exactness: the rebuild sees post-streaming / pre-collision values, same
@@ -172,10 +172,10 @@ def nebb_boundary_pass(f_pre, out, types, cfg, lat, interpret, tiles, rows,
 
     with phase_scope("lbm.phase.boundary"):
         f_in = jnp.moveaxis(nebb_stream_tiles(
-            f_pre, types, tiles, rows, lat, a=cfg.a, interpret=interpret,
+            f_pre, mask, tiles, rows, lat, a=cfg.a, interpret=interpret,
             node_order=cfg.node_order), 0, 1)               # (Q, B, n)
-        for mask, (_, spec) in zip(type_masks, cfg.boundaries):
-            f_in = apply_open_boundary(f_in, mask, spec, lat)
+        for on_face, (_, spec) in zip(type_masks, cfg.boundaries):
+            f_in = apply_open_boundary(f_in, on_face, spec, lat)
         f_out, _, _ = col.collide(f_in, lat, cfg.collision, cfg.force)
         f_out = jnp.where(solid[None], 0.0, f_out)
         return out.at[tiles].set(jnp.moveaxis(f_out, 0, 1))
@@ -343,8 +343,9 @@ class FusedBackend:
 
     def __init__(self, cfg, lat, tiling: Tiling, tables: StreamTables,
                  interpret: bool):
-        from repro.kernels.stream_collide import (build_neighbor_table,
-                                                  kernel_node_types)
+        from repro.kernels.stream_collide import (blocks_per_tile,
+                                                  build_bounce_mask,
+                                                  build_neighbor_table)
 
         if cfg.layout_scheme != "xyz":
             raise ValueError(
@@ -353,8 +354,9 @@ class FusedBackend:
         self.cfg, self.lat, self.tiling = cfg, lat, tiling
         self.interpret = interpret
         with obs.get_tracer().span("lbm.setup.backend_tables"):
-            self._types_np = kernel_node_types(tiling.node_types)  # (T+1,1,n)
             self._nbrs_np = build_neighbor_table(tiling, cfg.periodic)
+            self._mask_np = build_bounce_mask(          # (T+1, 1, n)
+                tiling.node_types, self._nbrs_np, lat, cfg.a, cfg.node_order)
             self._bc_np = (boundary_pass_tables(
                 tiling.node_types, self._nbrs_np, cfg.boundaries)
                 if cfg.boundaries and cfg.kernel_mode == "full" else None)
@@ -363,6 +365,9 @@ class FusedBackend:
             (tiling.node_types == SOLID, host))
         self._ens_tables: dict[int, dict] = {1: self.tables}
         reg = obs.get_metrics()
+        if reg.enabled:
+            reg.gauge("lbm.kernel.blocks_per_tile").set(
+                blocks_per_tile(lat, cfg.a, cfg.node_order))
         if reg.enabled and self._bc_np is not None:
             b = len(self._bc_np[0])
             reg.gauge("lbm.nebb.tiles").set(b)
@@ -389,13 +394,13 @@ class FusedBackend:
         cfg = self.cfg
         # scoped inside: lbm.phase.stream_collide, lbm.phase.pack
         out = stream_collide_tiles(
-            f, tab["types"], tab["nbrs"], self.lat, cfg.collision,
+            f, tab["mask"], tab["nbrs"], self.lat, cfg.collision,
             a=cfg.a, force=cfg.force, interpret=self.interpret,
             mode=cfg.kernel_mode, node_order=cfg.node_order)
         if "bc" in tab:
             bc = tab["bc"]
             out = nebb_boundary_pass(
-                f, out, tab["types"], cfg, self.lat, self.interpret,
+                f, out, tab["mask"], cfg, self.lat, self.interpret,
                 bc["tiles"], bc["nbrs"], bc["type_masks"], bc["solid"])
         return out
 
@@ -431,9 +436,9 @@ class FusedBackend:
                 [np.where(nb == t, batch * t, nb + b * t)
                  for b in range(batch)]).astype(np.int32)
 
-        types = np.concatenate([self._types_np[:t]] * batch
-                               + [self._types_np[t:]])
-        tab = {"types": types, "nbrs": replicate(self._nbrs_np)}
+        mask = np.concatenate([self._mask_np[:t]] * batch
+                              + [self._mask_np[t:]])
+        tab = {"mask": mask, "nbrs": replicate(self._nbrs_np)}
         if self._bc_np is not None:
             bt, rows, type_masks, solid_b = self._bc_np
             tab["bc"] = {

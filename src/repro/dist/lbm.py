@@ -474,25 +474,30 @@ class ShardedLBM:
 
     def _build_fused_tables(self, tbl, specs, types, periodic) -> None:
         """Per-slab tables for the fused kernel: neighbour tables (dummy
-        slot = scratch tile) and the boundary-pass tables (boundary tiles
-        and their neighbour rows)."""
+        slot = scratch tile), bounce masks and the boundary-pass tables
+        (boundary tiles and their neighbour rows)."""
         from repro.core.backends import boundary_pass_tables
-        from repro.kernels.stream_collide import build_neighbor_table
+        from repro.kernels.stream_collide import (build_bounce_mask,
+                                                  build_neighbor_table)
 
         cfg, plan = self.cfg, self.plan
         n = plan.nodes_per_tile
         d_cnt, dummy = plan.n_dev, plan.t_pad - 1
 
-        # the kernel's (Tp, 1, n) int32 type table per slab; padding tiles
-        # and the dummy slot are SOLID (0) already
-        tbl["types"] = types.astype(np.int32)[:, :, None, :]
-        specs["types"] = P("slab", None, None, None)
         nbrs = np.full((d_cnt, dummy, 27), dummy, np.int32)
+        mask = np.empty((d_cnt, plan.t_pad, 1, n), np.int32)
         for d, lt in enumerate(plan.local_tilings):
             nb = build_neighbor_table(lt, periodic)     # scratch = t_loc
             nbrs[d, :lt.num_tiles] = np.where(nb == lt.num_tiles, dummy, nb)
+            # the kernel's (Tp, 1, n) bounce mask per slab; padding tiles
+            # and the dummy slot are SOLID (0) in ``types``, so every bit
+            # of theirs is set
+            mask[d] = build_bounce_mask(types[d, :dummy], nbrs[d], self.lat,
+                                        cfg.a, cfg.node_order)
         tbl["nbrs"] = nbrs
         specs["nbrs"] = P("slab", None, None)
+        tbl["mask"] = mask
+        specs["mask"] = P("slab", None, None, None)
 
         if not (cfg.boundaries and cfg.kernel_mode == "full"):
             return
@@ -721,14 +726,14 @@ class ShardedLBM:
                 f = self._exchange_halo(f, tbl)
             # scoped inside: lbm.phase.stream_collide, lbm.phase.pack
             out = stream_collide_tiles(
-                f, tbl["types"][0], tbl["nbrs"][0], lat, cfg.collision,
+                f, tbl["mask"][0], tbl["nbrs"][0], lat, cfg.collision,
                 a=cfg.a, force=cfg.force, interpret=self.kernel_interpret,
                 mode=cfg.kernel_mode, node_order=cfg.node_order)
             if "bcn" in tbl:
                 # masked NEBB pass (shared with FusedBackend): re-stream +
                 # rebuild + collide ONLY the boundary tiles, pre-step state
                 out = nebb_boundary_pass(
-                    f, out, tbl["types"][0], cfg, lat, self.kernel_interpret,
+                    f, out, tbl["mask"][0], cfg, lat, self.kernel_interpret,
                     tbl["bct"][0], tbl["bcn"][0], tbl["bcm"][:, 0],
                     tbl["bcs"][0])
                 with obs.phase_scope("lbm.phase.pack"):

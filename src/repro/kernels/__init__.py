@@ -14,12 +14,14 @@ Kernels run compiled on the TPU and in interpret mode on the CPU; any
 other platform is refused (``ops.default_interpret``).
 """
 from .ops import collide_tiles, default_interpret, resolve_interpret
-from .stream_collide import (build_neighbor_table, nebb_stream_tiles,
-                             pack_engine_state, stream_collide_tiles,
-                             unpack_engine_state, zero_scratch_row)
+from .stream_collide import (build_bounce_mask, build_neighbor_table,
+                             nebb_stream_tiles, pack_engine_state,
+                             stream_collide_tiles, unpack_engine_state,
+                             zero_scratch_row)
 
 __all__ = [
     "collide_tiles", "default_interpret", "resolve_interpret",
-    "build_neighbor_table", "nebb_stream_tiles", "pack_engine_state",
-    "stream_collide_tiles", "unpack_engine_state", "zero_scratch_row",
+    "build_bounce_mask", "build_neighbor_table", "nebb_stream_tiles",
+    "pack_engine_state", "stream_collide_tiles", "unpack_engine_state",
+    "zero_scratch_row",
 ]

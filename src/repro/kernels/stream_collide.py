@@ -18,10 +18,18 @@ neighbours point at it, so half-way bounce-back falls out of the ordinary
 Pull geometry: node x pulls f_q from x - e_q, which lies in this tile or in
 one of the D3Q19 linkage neighbours — for DIAGONAL directions an edge/corner
 node's source may sit in a FACE neighbour rather than the diagonal one, so
-the kernel loads all 18 linked neighbour blocks (6 faces + 12 edges) once
-and a static per-(direction, node) CASE table picks the source block.  All
-tables are host-built numpy constants shipped as kernel inputs, exactly
-like the paper builds its indices once on CPU.
+the kernel loads all 18 linked neighbour f blocks (6 faces + 12 edges) once
+and a static per-(direction, node) CASE table picks the source block.
+
+Whether a pull's source is solid depends only on the geometry and the
+neighbour table, so it is not recomputed per step: a per-tile BOUNCE MASK
+(:func:`build_bounce_mask`, one int32 word per node) holds it, bit q set
+when the pull of direction q reads a solid node (scratch tile included)
+and bit Q set when the node itself is solid.  Each grid step therefore
+DMAs the own f and mask blocks and the 18 neighbour f blocks
+(:func:`blocks_per_tile`).  All tables are host-built numpy constants
+shipped as kernel inputs, exactly like the paper builds its indices once
+on CPU.
 
 The kernel computes in the storage dtype (float32 on TPU, float64 for the
 CPU validation runs), so the float64 parity tests against the gather
@@ -135,59 +143,98 @@ def build_neighbor_table(
     return np.where(nbr < 0, t, nbr).astype(np.int32)
 
 
+def build_bounce_mask(node_types: np.ndarray, neighbors: np.ndarray,
+                      lat: Lattice, a: int = 4,
+                      node_order: str = "canonical") -> np.ndarray:
+    """The kernel's (T+1, 1, n) int32 bounce mask.
+
+    node_types: (T, n) node types in ``node_order`` slots
+    neighbors:  (T, 27) the kernel's neighbour table (scratch index T,
+                periodic wraps included: :func:`build_neighbor_table`)
+
+    Bit q (q < Q) of ``mask[t, 0, i]`` is set when the pull of direction q
+    into node i of tile t reads a SOLID source: the node that ``perms``
+    picks in the tile that ``cases`` picks, through ``neighbors``.  Bit Q
+    is set when node i itself is SOLID.  The scratch row T has every bit
+    set.  The unit middle axis makes each per-tile block's last two dims
+    equal the array's (Mosaic's block-shape rule).  Built one (direction,
+    node) pull at a time over node-major rows, so that each pull reads one
+    contiguous row of its source node's solidity, gathered by source tile
+    where that lies in a neighbour (0.7 s at 236k tiles on one host core).
+    """
+    t, n = node_types.shape
+    offsets, perms, cases = _pull_geometry(lat, a, node_order)
+    solid = np.ones((n, t + 1), np.int32)
+    solid[:, :t] = (node_types == SOLID).T
+    src = neighbors[:, [neighbor_offset_index(*off) for off in offsets]].T
+    bits = solid[:, :t] << lat.q                                    # (n, T)
+    for q in range(lat.q):
+        for i in range(n):
+            c = cases[q, i]
+            row = solid[perms[q, i]]
+            bits[i] |= (row[:t] if c == 0 else row.take(src[c - 1])) << q
+    mask = np.full((t + 1, 1, n), (2 << lat.q) - 1, np.int32)
+    mask[:t, 0] = bits.T
+    return mask
+
+
+def blocks_per_tile(lat: Lattice, a: int = 4,
+                    node_order: str = "canonical") -> int:
+    """Per-tile input blocks each grid step of the pull DMAs: the own f
+    and bounce-mask blocks, and the f block of each linked neighbour."""
+    return 2 + len(_pull_geometry(lat, a, node_order)[0])
+
+
 def make_kernel(lat: Lattice, cfg: col.CollisionConfig, n_offsets: int,
                 force=None, mode: str = "full"):
     """Kernel body for one tile.
 
     Mosaic constraints shape it: every in-kernel value is 2-D ((Q, n)
     blocks or (1, n) direction rows), and the pull is a 2-D lane gather
-    ``take_along_axis(block, perms, axis=1)`` over the whole (Q, n) block
-    (a 1-D ``jnp.take`` per direction does not lower; a one-hot (n, n)
-    selection on the MXU would cost Q*n^2 MACs per block and round float32
-    operands unless run at HIGHEST precision, while the gather is exact
-    and stays on the VPU/XLU).  The static row
-    permutation ``f[opp]`` and the per-direction rows the collision needs
-    go through the output block itself as a staging buffer: single-row
-    ref loads/stores lower where value-level row shuffles do not, and the
-    block is fully overwritten before the kernel returns.
+    ``take_along_axis(block, perms, axis=1)`` over the whole (Q, n) f
+    block (a 1-D ``jnp.take`` per direction does not lower; a one-hot
+    (n, n) selection on the MXU would cost Q*n^2 MACs per block and round
+    float32 operands unless run at HIGHEST precision, while the gather is
+    exact and stays on the VPU/XLU).  Which pulls bounce back, and which
+    nodes are solid, is read from the own tile's bounce mask row
+    (:func:`build_bounce_mask`) by a shift per direction row, not gathered
+    from the neighbours.  The static row permutation ``f[opp]`` and the
+    per-direction rows the collision needs go through the output block
+    itself as a staging buffer: single-row ref loads/stores lower where
+    value-level row shuffles do not, and the block is fully overwritten
+    before the kernel returns.
     """
     q = lat.q
     opp = [int(o) for o in lat.opp]
     mrt = cfg.model == col.LBMRT and mode == "full"
 
-    def kernel(start_ref, nb_ref, own_f, own_t, perms_ref, cases_ref, *rest):
-        # rest: (f, types) x n_offsets, [A], aliased output buffer, out
+    def kernel(start_ref, nb_ref, own_f, own_m, perms_ref, cases_ref, *rest):
+        # rest: neighbour f x n_offsets, [A], aliased output buffer, out
         out_ref = rest[-1]
         a_ref = rest[-3] if mrt else None
-        nbr = rest[:2 * n_offsets]            # (f_off, t_off) x n_offsets
         perms = perms_ref[...]                # (Q, n) int32
         cases = cases_ref[...]                # (Q, n) int32
         n = perms.shape[1]
 
-        def pull(f_blk, t_row):
-            """(Q, n) sources and their node types from one tile block."""
-            t_q = jnp.broadcast_to(t_row, (q, n))
-            return (jnp.take_along_axis(f_blk, perms, axis=1),
-                    jnp.take_along_axis(t_q, perms, axis=1))
-
-        t_own = own_t[0]                      # (1, n) int32
-        src_f, src_t = pull(own_f[0], t_own)
+        src_f = jnp.take_along_axis(own_f[0], perms, axis=1)
         for c in range(n_offsets):
-            hit = cases == (c + 1)
-            nf, nt = pull(nbr[2 * c][0], nbr[2 * c + 1][0])
-            src_f = jnp.where(hit, nf, src_f)
-            src_t = jnp.where(hit, nt, src_t)
+            src_f = jnp.where(cases == (c + 1),
+                              jnp.take_along_axis(rest[c][0], perms, axis=1),
+                              src_f)
 
         # half-way bounce-back: a solid source returns the node's own
         # opposite population, f_own[opp[q]]
+        m_own = own_m[0]                      # (1, n) int32
+        row = jax.lax.broadcasted_iota(jnp.int32, (q, n), 0)
+        bounce = ((jnp.broadcast_to(m_own, (q, n)) >> row) & 1) == 1
         for i in range(q):
             out_ref[0, i:i + 1, :] = own_f[0, opp[i]:opp[i] + 1, :]
-        f_in = jnp.where(src_t == SOLID, out_ref[0], src_f)   # (Q, n)
+        f_in = jnp.where(bounce, out_ref[0], src_f)   # (Q, n)
         out_ref[0] = f_in
         if mode == "propagation_only":
             return
 
-        solid_row = t_own == SOLID            # (1, n)
+        solid_row = ((m_own >> q) & 1) == 1   # (1, n)
         rows = [out_ref[0, i:i + 1, :] for i in range(q)]
         feq = _equilibrium_rows(rows, solid_row, lat, cfg, force)
         if not mrt:
@@ -202,30 +249,29 @@ def make_kernel(lat: Lattice, cfg: col.CollisionConfig, n_offsets: int,
         f_out = f_in + jnp.dot(a_ref[...], out_ref[0],
                                preferred_element_type=f_in.dtype,
                                precision=jax.lax.Precision.HIGHEST)
-        out_ref[0] = jnp.where(jnp.broadcast_to(t_own, (q, n)) == SOLID,
+        out_ref[0] = jnp.where(jnp.broadcast_to(solid_row, (q, n)),
                                0.0, f_out)
 
     return kernel
 
 
-def _pull_inputs(f, node_types, lat: Lattice, a: int, node_order: str,
-                 nw: int, own_map):
+def _pull_inputs(f, mask, lat: Lattice, a: int, node_order: str, nw: int,
+                 own_map):
     """BlockSpecs and operands of the pull, shared by the kernel's two
     calls (:func:`stream_collide_tiles`, :func:`nebb_stream_tiles`): the
-    own tile's f and types blocks (``own_map``), the static perms/cases
-    tables, then f and types of each linked neighbour tile, whose id the
-    index map reads from the neighbour rows prefetched as the LAST
-    scalar-prefetch operand (``nw`` entries per grid step)."""
+    own tile's f and bounce-mask blocks (``own_map``), the static
+    perms/cases tables, then the f block of each linked neighbour tile,
+    whose id the index map reads from the neighbour rows prefetched as the
+    LAST scalar-prefetch operand (``nw`` entries per grid step)."""
     q, n = f.shape[1], f.shape[2]
     offsets, perms, cases = _pull_geometry(lat, a, node_order)
     table_spec = pl.BlockSpec((q, n), lambda i, *_: (0, 0))
     in_specs = [
         pl.BlockSpec((1, q, n), own_map),                    # own f
-        pl.BlockSpec((1, 1, n), own_map),                    # own types
+        pl.BlockSpec((1, 1, n), own_map),                    # own mask
         table_spec, table_spec,                              # perms, cases
     ]
-    operands = [f, node_types, jnp.asarray(perms),
-                jnp.asarray(cases, jnp.int32)]
+    operands = [f, mask, jnp.asarray(perms), jnp.asarray(cases, jnp.int32)]
     for off in offsets:
         k = neighbor_offset_index(*off)
 
@@ -233,8 +279,7 @@ def _pull_inputs(f, node_types, lat: Lattice, a: int, node_order: str,
             return (prefetched[-1][i * nw + _k], 0, 0)
 
         in_specs.append(pl.BlockSpec((1, q, n), nb_map))
-        in_specs.append(pl.BlockSpec((1, 1, n), nb_map))
-        operands.extend([f, node_types])
+        operands.append(f)
     return in_specs, operands
 
 
@@ -250,18 +295,18 @@ def zero_scratch_row(f: jnp.ndarray, row: int) -> jnp.ndarray:
     return jax.lax.dynamic_update_slice(f, zeros, (row,) + (0,) * (f.ndim - 1))
 
 
-def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
+def stream_collide_tiles(f, mask, neighbors, lat: Lattice,
                          cfg: col.CollisionConfig, a: int = 4, force=None,
                          interpret: bool | None = None, mode: str = "full",
                          node_order: str = "canonical"):
     """One fused LBM step over all tiles.
 
     f:          (T+1, Q, n) — scratch tile at index T must be zero
-    node_types: (T+1, 1, n) int32 (:func:`kernel_node_types`) — scratch
-                tile must be SOLID
+    mask:       (T+1, 1, n) int32 bounce mask (:func:`build_bounce_mask`
+                over ``neighbors``) — scratch row all bits set
     neighbors:  (T, 27) int32 — empty/out-of-grid entries = T (scratch)
     mode:       'full' | 'propagation_only' | 'rw_only' (paper §4.1)
-    node_order: within-tile node enumeration the caller's f/node_types use
+    node_order: within-tile node enumeration the caller's f/mask use
                 (repro.core.tiling.NODE_ORDERS); the static pull tables are
                 remapped to match
     interpret:  None = auto (:func:`repro.kernels.ops.default_interpret`)
@@ -296,13 +341,13 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
     offsets = _pull_geometry(lat, a, node_order)[0]
     kernel = make_kernel(lat, cfg, len(offsets), force, mode)
 
-    assert node_types.shape == (t1, 1, n), node_types.shape
+    assert mask.shape == (t1, 1, n), mask.shape
     nw = neighbors.shape[1]
 
     def own_map(i, start, nb):
         return (start[0] + i, 0, 0)
 
-    in_specs, operands = _pull_inputs(f, node_types, lat, a, node_order, nw,
+    in_specs, operands = _pull_inputs(f, mask, lat, a, node_order, nw,
                                       own_map)
     if cfg.model == col.LBMRT and mode == "full":
         in_specs.append(pl.BlockSpec((q, q), lambda i, start, nb: (0, 0)))
@@ -348,13 +393,13 @@ def stream_collide_tiles(f, node_types, neighbors, lat: Lattice,
         return zero_scratch_row(out, t)
 
 
-def nebb_stream_tiles(f, node_types, tiles, rows, lat: Lattice, a: int = 4,
+def nebb_stream_tiles(f, mask, tiles, rows, lat: Lattice, a: int = 4,
                       interpret: bool | None = None,
                       node_order: str = "canonical"):
     """The fused kernel's pull over a LIST of tiles: streaming plus
     half-way bounce-back, no collision (``mode='propagation_only'``).
 
-    f, node_types: as in :func:`stream_collide_tiles` (scratch row last)
+    f, mask:    as in :func:`stream_collide_tiles` (scratch row last)
     tiles:      (B,) int32 tile rows of ``f`` to pull
     rows:       (B, 27) int32 their neighbour-table rows (``nbrs[tiles]``)
     Returns the post-streaming, pre-collision (B, Q, n) block.
@@ -382,7 +427,7 @@ def nebb_stream_tiles(f, node_types, tiles, rows, lat: Lattice, a: int = 4,
     def own_map(i, start, ids, nb):
         return (ids[i], 0, 0)
 
-    in_specs, operands = _pull_inputs(f, node_types, lat, a, node_order, nw,
+    in_specs, operands = _pull_inputs(f, mask, lat, a, node_order, nw,
                                       own_map)
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # aliased output
     chunk = min(b, TILES_PER_CALL)
@@ -413,27 +458,17 @@ def nebb_stream_tiles(f, node_types, tiles, rows, lat: Lattice, a: int = 4,
                              jnp.zeros((b, q, n), f.dtype))
 
 
-def kernel_node_types(node_types: np.ndarray) -> np.ndarray:
-    """(T, n) node types -> the kernel's (T+1, 1, n) int32 table with the
-    all-SOLID scratch row appended.  The unit middle axis makes every
-    per-tile block's last two dims equal the array's (Mosaic's block-shape
-    rule), and int32 is the element type Mosaic gathers over lanes."""
-    t, n = node_types.shape
-    out = np.full((t + 1, 1, n), SOLID, np.int32)
-    out[:t, 0] = node_types
-    return out
-
-
 def pack_engine_state(tiling: Tiling, f_canon, lat: Lattice):
-    """(Q, T, n) canonical engine state -> kernel inputs."""
+    """(Q, T, n) canonical engine state -> kernel inputs: f, bounce mask,
+    neighbour table."""
     t, n = tiling.num_tiles, tiling.nodes_per_tile
     f = jnp.zeros((t + 1, lat.q, n), f_canon.dtype)
     f = f.at[:t].set(jnp.moveaxis(f_canon, 0, 1))
-    types = jnp.asarray(kernel_node_types(tiling.node_types))
-    nbrs = jnp.asarray(
-        np.where(tiling.tile_neighbors < 0, t, tiling.tile_neighbors)
-        .astype(np.int32))
-    return f, types, nbrs
+    nbrs = np.where(tiling.tile_neighbors < 0, t,
+                    tiling.tile_neighbors).astype(np.int32)
+    mask = build_bounce_mask(tiling.node_types, nbrs, lat, tiling.a,
+                             tiling.node_order)
+    return f, jnp.asarray(mask), jnp.asarray(nbrs)
 
 
 def unpack_engine_state(f_packed):

@@ -62,6 +62,9 @@ CATALOGUE = {
     "lbm.nebb.tiles": "gauge: boundary tiles the fused NEBB pass "
                       "re-streams per step",
     "lbm.nebb.tile_share": "gauge: lbm.nebb.tiles / all tiles",
+    "lbm.kernel.blocks_per_tile": "gauge: per-tile input blocks each grid "
+                                  "step of the fused kernel DMAs (own f, "
+                                  "own bounce mask, neighbour f)",
     # ---- serving layer ------------------------------------------------
     "sim.session.submitted_total": "counter: sessions submitted",
     "sim.session.admitted_total": "counter: sessions seated into slots",

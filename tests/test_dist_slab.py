@@ -139,7 +139,7 @@ FUSED_TABLE_DIGESTS = {
     "rd": "f358e613aff5a6e2", "rdm": "718965407a54beef",
     "ru": "9a638aaa3e7eb18f", "rum": "0b8fb940f0071727",
     "sd": "6d44d9f2e833a0cc", "solid": "e3e1cd7c06d32910",
-    "su": "84eba82789f43ac7", "types": "94b3b7b3d27bdb94",
+    "su": "84eba82789f43ac7", "mask": "e59e73dcbc35aad3",
 }
 
 
@@ -166,6 +166,27 @@ def test_fused_sharded_tables_keep_their_bits():
         got[k] = hashlib.sha256(
             v.tobytes() + str((v.shape, v.dtype)).encode()).hexdigest()[:16]
     assert got == FUSED_TABLE_DIGESTS
+
+
+def test_fused_sharded_mask_bounces_padding_and_dummy_rows():
+    """Each slab's bounce mask: the rows past its tiles (padding to the
+    fullest slab, then the dummy row) bounce every pull and are SOLID, and
+    its tiles' rows are the mask of the slab's own tiling, halo included."""
+    from repro.core.lattice import d3q19
+    from repro.kernels.stream_collide import (build_bounce_mask,
+                                              build_neighbor_table)
+
+    _, eng = _fused_sharded()
+    mask, plan = eng._tbl_np["mask"], eng.plan
+    assert mask.shape == (plan.n_dev, plan.t_pad, 1, 64)
+    all_bits = (2 << 19) - 1
+    held = [lt.num_tiles for lt in plan.local_tilings]
+    assert min(held) < plan.t_pad - 1          # some slab has padding rows
+    for d, lt in enumerate(plan.local_tilings):
+        assert (mask[d, held[d]:] == all_bits).all()
+        own = build_bounce_mask(lt.node_types, build_neighbor_table(lt),
+                                d3q19())
+        np.testing.assert_array_equal(mask[d, :held[d]], own[:-1])
 
 
 def test_owned_node_coords_cover_the_tiling_once():

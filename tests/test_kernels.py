@@ -132,12 +132,12 @@ def test_fused_kernel_chunked_grid_matches_single_call(monkeypatch):
                     periodic=(True, True, True), u0=(0.01, 0.0, 0.02))
     eng = SparseTiledLBM(g, cfg)
     lat = d3q19()
-    fp, types, nbrs = sc.pack_engine_state(eng.tiling, eng.f, lat)
+    fp, mask, nbrs = sc.pack_engine_state(eng.tiling, eng.f, lat)
     t = eng.tiling.num_tiles
     assert t % 5, "pick a chunk that leaves a partial last chunk"
-    whole = sc.stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
+    whole = sc.stream_collide_tiles(fp, mask, nbrs, lat, cfg.collision,
                                     interpret=True)
     monkeypatch.setattr(sc, "TILES_PER_CALL", t // 5)
-    chunked = sc.stream_collide_tiles(fp, types, nbrs, lat, cfg.collision,
+    chunked = sc.stream_collide_tiles(fp, mask, nbrs, lat, cfg.collision,
                                       interpret=True)
     np.testing.assert_array_equal(np.asarray(chunked), np.asarray(whole))

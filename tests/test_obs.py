@@ -449,6 +449,26 @@ def test_nebb_gauges_absent_without_boundaries():
     assert reg.value("lbm.nebb.tile_share") is None
 
 
+def test_kernel_blocks_per_tile_gauge():
+    """The fused backend reports, at construction, the per-tile input
+    blocks each grid step of its kernel DMAs: the own f and bounce-mask
+    blocks and the 18 linked neighbours' f blocks (D3Q19); the pull that
+    gathered node types took 38.  The gather backend and a disabled
+    registry get nothing."""
+    reg = MetricRegistry()
+    with obs.use(metrics=reg):
+        _tiny_duct("fused")
+    assert reg.value("lbm.kernel.blocks_per_tile") == 20
+    gather = MetricRegistry()
+    with obs.use(metrics=gather):
+        _tiny_duct("gather")
+    assert gather.value("lbm.kernel.blocks_per_tile") is None
+    off = MetricRegistry(enabled=False)
+    with obs.use(metrics=off):
+        _tiny_duct("fused")
+    assert off.value("lbm.kernel.blocks_per_tile") is None
+
+
 def test_model_metrics_names_and_sanity():
     eng = _tiny_engine(split_stream=True)
     m = eng.model_metrics()

@@ -98,11 +98,11 @@ def test_fused_kernel_compiles(one_chip, model):
     cfg = C.CollisionConfig(model=model, tau=0.7)
     f = jax.ShapeDtypeStruct((t + 1, lat.q, 64), jnp.float32,
                              sharding=one_chip)
-    types = jax.ShapeDtypeStruct((t + 1, 1, 64), jnp.int32,
-                                 sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((t + 1, 1, 64), jnp.int32,
+                                sharding=one_chip)
     nbrs = jax.ShapeDtypeStruct((t, 27), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(lambda f, ty, nb: stream_collide_tiles(
-        f, ty, nb, lat, cfg, interpret=False)).lower(f, types, nbrs).compile()
+    compiled = jax.jit(lambda f, m, nb: stream_collide_tiles(
+        f, m, nb, lat, cfg, interpret=False)).lower(f, mask, nbrs).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -124,15 +124,54 @@ def _compiled_run_kernels(eng, sharding):
     return text, {k.split(".")[0] for k in kernels}
 
 
-def test_fused_run_names_its_kernel_and_boundary_pass(one_chip, spheres):
+@pytest.fixture(scope="module")
+def fused_run(one_chip, spheres):
+    """The fused engine's compiled ``run(10)`` on the ``spheres`` case:
+    its text and the names of its Pallas calls."""
+    return _compiled_run_kernels(_engine(spheres, backend="fused"), one_chip)
+
+
+def test_fused_run_names_its_kernel_and_boundary_pass(fused_run):
     """The compiled ``run()`` holds the fused kernel and the NEBB pass's
     tile-list pull, each under its own name, no other Pallas kernel, and
     no XLA gather: the boundary tiles are re-streamed block by block by
     the kernel's pull, not element by element from the flattened state."""
-    text, names = _compiled_run_kernels(_engine(spheres, backend="fused"),
-                                        one_chip)
+    text, names = fused_run
     assert names == {"stream_collide", "nebb_stream"}, names
     assert " gather(" not in text
+
+
+def _call_operands(line):
+    """(name, shape) of each operand of one compiled custom call."""
+    args = re.search(r" custom-call\((.*?)\), custom_call_target", line)
+    names = re.findall(r"%[\w.-]+", args.group(1))
+    layouts = re.search(r"operand_layout_constraints=\{(.*?)\}, "
+                        r"output_to_operand_aliasing", line)
+    shapes = re.findall(r"\b[a-z]\w*\[[\d,]*\]", layouts.group(1))
+    assert len(names) == len(shapes), line
+    return list(zip(names, shapes))
+
+
+def test_fused_run_kernels_read_the_bounce_mask_not_node_types(fused_run):
+    """Both Pallas calls of the compiled ``run()`` take the state f 19
+    times (the own tile and its 18 linked neighbours) and the (T+1, 1, n)
+    int32 bounce mask once: no per-neighbour node-type table, whose
+    blocks would come in 19 times with the mask's shape."""
+    text, _ = fused_run
+    t1 = SPHERES_TILES + 1
+    state, mask = f"f32[{t1},19,64]", f"s32[{t1},1,64]"
+    seen = set()
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        kernel = re.match(r"\s*(?:ROOT )?%([\w-]+)", line).group(1)
+        ops = _call_operands(line)
+        f_names = [name for name, shape in ops if shape == state]
+        assert max(f_names.count(name) for name in f_names) == 19, kernel
+        assert [shape for _, shape in ops].count(mask) == 1, kernel
+        assert not any(shape.startswith(("s8[", "u8[")) for _, shape in ops)
+        seen.add(kernel)
+    assert seen == {"stream_collide", "nebb_stream"}, seen
 
 
 def test_fused_run_without_boundaries_names_one_kernel(one_chip, spheres):
